@@ -371,7 +371,7 @@ def check_bench9() -> list:
     structurally (a legal, sublane-aligned survivor). The *static* half
     is recomputed exactly: candidate/pruned counts, prune reasons, and
     survivor sets come from ``benchmarks.tune.static_prune`` (grid-pass
-    legality + headroom budget), so any change to the candidate list,
+    legality, VMEM against the scoped limit), so any change to the candidate list,
     the prune rules, or the verifier's legality verdicts shows up as
     drift against the committed document."""
     if not BENCH9.exists():

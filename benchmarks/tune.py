@@ -1,7 +1,7 @@
 """Statically-pruned row-block autotuner for the tile kernels (PR 9).
 
-``make_tile_op`` autosizes one ``row_block`` per kernel from the declared
-geometry (``pick_row_block``); this driver searches the block-shape space
+A tile op sizes its ``row_block`` per call (``pick_row_block``, against
+the scoped VMEM limit); this driver searches the block-shape space
 around that default — but instead of timing every candidate, it first
 runs each through the symbolic grid verifier
 (:func:`repro.verify.grid_check.check_tile_kernel_grid`) and **prunes
@@ -11,12 +11,9 @@ statically**:
   is 8 sublanes; misaligned blocks relayout on every load);
 * ``exceeds-rows``       — larger than the tuning geometry's row count
   (``plan_tile_call`` would clamp it to a duplicate of ``rows``);
-* any grid-pass **error** (``grid-vmem-overflow``, ``grid-oob-read``,
-  ...) — the candidate is illegal, not merely slow;
-* ``vmem-headroom``      — the exact double-buffer-aware footprint
-  busts the 4x-headroom autosizing budget (legal but compiler-hostile:
-  the same register-pressure concern, paper §VIII, that caps the
-  default).
+* any grid-pass **error** (``grid-vmem-overflow`` against the scoped
+  VMEM limit, ``grid-oob-read``, ...) — the candidate is illegal, not
+  merely slow.
 
 Only the survivors are measured (interleaved round-robin with the
 ``measure.py`` gc/rotation discipline — every candidate runs the same
@@ -90,8 +87,8 @@ def _op_for(name: str):
 def static_prune(name: str, rows: int = TUNE_ROWS) -> dict:
     """Classify every candidate row block for one kernel — no timing,
     no randomness; the grid verifier is the only legality oracle."""
-    from repro.core.hardware import DEFAULT_CHIP
-    from repro.core.pallasgen import _declared_feature_dim
+    from repro.core.pallasgen import (_declared_dtype_bytes,
+                                      _declared_feature_dim)
     from repro.verify.grid_check import check_tile_kernel_grid
 
     if rows % 8:
@@ -99,8 +96,8 @@ def static_prune(name: str, rows: int = TUNE_ROWS) -> dict:
                          f"of 8), got {rows}")
     op = _op_for(name)
     prog = op.sk.ssa.prog
-    budget = DEFAULT_CHIP.vmem_bytes // 4     # pick_row_block's headroom
-    default_rb = op.row_block
+    default_rb = op.block_rows(_declared_feature_dim(prog) or 256,
+                               _declared_dtype_bytes(prog))
     # what the default actually runs at this geometry: plan_tile_call
     # clamps row_block to the row count, so the baseline the winner must
     # beat is the clamped block, not the (possibly larger) autosized one
@@ -119,9 +116,6 @@ def static_prune(name: str, rows: int = TUNE_ROWS) -> dict:
             errors = [f for f in res.findings if f.severity == "error"]
             if errors:
                 entry.update(status="pruned", reason=errors[0].code)
-            elif res.vmem_bytes > budget:
-                entry.update(status="pruned", reason="vmem-headroom",
-                             vmem_bytes=res.vmem_bytes)
             else:
                 entry.update(status="survivor", vmem_bytes=res.vmem_bytes)
         entries.append(entry)

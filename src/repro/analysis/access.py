@@ -260,8 +260,9 @@ class BlockAccess:
     ``array_shape`` is the shape of the buffer actually passed to the
     call — i.e. *after* any host-side padding (``_ceil_to``), so the
     pad region is modeled explicitly as in-bounds. ``buffers`` is the
-    VMEM copy count (2 when the pipelined emitter stages the operand
-    through a double-buffer scratch in addition to its block window).
+    VMEM copy count: 2 for a blocked operand, which compiled Pallas
+    double-buffers across grid steps, 3 when the pipelined emitter also
+    stages it through a scratch buffer.
     """
     array: str
     mode: str                       # "read" | "write"
@@ -269,7 +270,7 @@ class BlockAccess:
     array_shape: Tuple[int, ...]
     index_map: Callable
     dtype_bytes: int = 4
-    buffers: int = 1
+    buffers: int = 2
 
     def __post_init__(self):
         if self.mode not in ("read", "write"):

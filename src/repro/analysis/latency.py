@@ -200,8 +200,7 @@ class LatencyModel:
     # -- schedule-aware objective (PR 5) ------------------------------------
     def vmem_budget_bytes(self) -> int:
         """Working-set budget for the pressure term: a quarter of the
-        chip's VMEM, matching ``pick_row_block``'s headroom for compiler
-        temporaries."""
+        chip's VMEM, headroom for compiler temporaries."""
         return int(self.chip.vmem_bytes) // 4
 
     def schedule_ns(self, events: Sequence[ScheduleEvent], *,

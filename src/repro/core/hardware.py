@@ -1,21 +1,30 @@
-"""TPU hardware constants used by cost models, roofline, and VMEM sizing.
+"""Chip constants used by cost models, roofline, and VMEM sizing.
 
-Target: TPU v5e (the assignment's roofline constants). A100 numbers are
-kept for the paper-comparison ablation in EXPERIMENTS.md.
+Target: TPU v5e. ``chip_for_device_kind`` maps the ``device_kind`` string
+JAX reports (``jax.devices()[0].device_kind``) to its spec; a device that
+is not in :data:`CHIPS_BY_DEVICE_KIND` is an error, never a default.
 """
 from __future__ import annotations
 
 import dataclasses
+from typing import Dict
+
+MiB = 1024 ** 2
 
 
 @dataclasses.dataclass(frozen=True)
 class ChipSpec:
     name: str
+    device_kind: str            # jax.devices()[i].device_kind
     peak_flops_bf16: float      # FLOP/s (matrix unit)
     hbm_bw: float               # bytes/s
     ici_bw_per_link: float      # bytes/s per link
     hbm_bytes: int              # HBM capacity
-    vmem_bytes: int             # usable VMEM per core (conservative)
+    vmem_bytes: int             # VMEM the cost model prices pressure against
+    # scoped-VMEM limit every tile-op kernel requests from the compiler
+    # (pltpu.CompilerParams.vmem_limit_bytes); row-block autosizing and
+    # the grid verifier's VMEM pass budget against this same figure
+    vmem_limit_bytes: int = 48 * MiB
     ici_links: int = 4          # 2D torus: 4 links/chip
     # Vector-unit issue model (used by the unified analysis subsystem to
     # price elementwise tile passes): `vpu_lanes` elements retire per
@@ -29,29 +38,50 @@ class ChipSpec:
         return self.vpu_lanes * self.clock_hz
 
 
+# Published peaks: Google Cloud documentation, "TPU v5e" (197 TFLOP/s
+# bf16, 16 GB HBM at 819 GB/s). The chip has 128 MiB of VMEM; the cost
+# model prices against 64 MiB of it.
 TPU_V5E = ChipSpec(
     name="tpu_v5e",
-    peak_flops_bf16=197e12,     # assignment constant
-    hbm_bw=819e9,               # assignment constant
-    ici_bw_per_link=50e9,       # assignment constant (~50 GB/s/link)
+    device_kind="TPU v5 lite",
+    peak_flops_bf16=197e12,
+    hbm_bw=819e9,
+    ici_bw_per_link=50e9,
     hbm_bytes=16 * 1024**3,
-    vmem_bytes=64 * 1024**2,    # keep kernels well under the 128 MiB VMEM
+    vmem_bytes=64 * MiB,
 )
 
-# For the paper's own A100-PCIE-40GB evaluation (Fig. 2/4), used by the
-# ablation benchmark to relate our cost-model deltas to the paper's GPU.
+# The paper's own GPU (A100-PCIE-40GB, Fig. 2/4). Not a target here:
+# calibration tests use it as a second, non-default set of constants.
 A100_PCIE_40GB = ChipSpec(
     name="a100_pcie_40gb",
+    device_kind="NVIDIA A100-PCIE-40GB",
     peak_flops_bf16=312e12,
     hbm_bw=1555e9,
     ici_bw_per_link=64e9,       # NVLink3 per-direction aggregate/ring share
     hbm_bytes=40 * 1024**3,
-    vmem_bytes=192 * 1024,      # SMEM+L1 per SM — for commentary only
+    vmem_bytes=192 * 1024,      # SMEM+L1 per SM
     vpu_lanes=108 * 64,         # 108 SMs × 64 FP32 lanes
     clock_hz=1.41e9,
 )
 
 DEFAULT_CHIP = TPU_V5E
+
+# The accelerators this repo runs on, by the device_kind JAX reports.
+CHIPS_BY_DEVICE_KIND: Dict[str, ChipSpec] = {
+    TPU_V5E.device_kind: TPU_V5E,
+}
+
+
+def chip_for_device_kind(kind: str) -> ChipSpec:
+    """The spec of a device JAX reports as ``kind``; unknown kinds raise."""
+    try:
+        return CHIPS_BY_DEVICE_KIND[kind]
+    except KeyError:
+        raise ValueError(
+            f"unknown device_kind {kind!r}; known: "
+            f"{sorted(CHIPS_BY_DEVICE_KIND)}") from None
+
 
 # Mesh axis conventions used across the framework.
 AXIS_POD = "pod"

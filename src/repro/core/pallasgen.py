@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.runtime import chaos
 
@@ -55,10 +56,10 @@ from .schedule import compute_schedule
 from .ssa import LoopRegion, Region, SSAResult, StoreEffect
 from .hardware import DEFAULT_CHIP
 
-try:  # the TPU primitive set is optional at import time (CPU-only hosts)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - depends on the jax build
-    pltpu = None
+# Kernel bodies compute in f32 whatever the operands store: every load is
+# upcast and every store cast back to its ref's dtype (bf16 refs take
+# neither f32 values nor f32 scalar broadcasts on the TPU).
+_UPCAST = ".astype(jnp.float32)"
 
 
 def _on_cpu() -> bool:
@@ -163,7 +164,7 @@ class SyncPallasGenerator(JaxCodeGenerator):
             self.stats.n_loads += 1
             self.stats.instruction_mix["load"] = \
                 self.stats.instruction_mix.get("load", 0) + 1
-            lines.append(f"{indent}{name} = {arr}[...]")
+            lines.append(f"{indent}{name} = {arr}[...]{_UPCAST}")
             self.scope.bind(cid, name)
             return name
         return super().emit_value(cid, lines, indent)
@@ -175,8 +176,9 @@ class SyncPallasGenerator(JaxCodeGenerator):
             pred = self.emit_value(eff.pred_cid, lines, indent)
             src = self.scope.get_sym(eff.version_in)
             old = f"{src}[...]" if src else f"{dst_ref}[...]"
-            val = f"jnp.where({pred}, {val}, {old})"
-        lines.append(f"{indent}{dst_ref}[...] = {val}")
+            val = f"jnp.where({pred}, {val}, {old}{_UPCAST})"
+        lines.append(
+            f"{indent}{dst_ref}[...] = ({val}).astype({dst_ref}.dtype)")
         # later loads of this array read the ref we just wrote
         self.scope.bind_sym(eff.version_out, dst_ref)
         self.stats.n_stores += 1
@@ -247,10 +249,7 @@ class PallasGenerator(SyncPallasGenerator):
 
 
 _PIPELINED_PRELUDE = _PRELUDE + """
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # CPU-only build: callers run the sync fallback body
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 """
 
 
@@ -362,7 +361,7 @@ class PipelinedPallasGenerator(SyncPallasGenerator):
             self.stats.n_loads += 1
             self.stats.instruction_mix["load"] = \
                 self.stats.instruction_mix.get("load", 0) + 1
-            lines.append(f"{indent}{name} = {cp.buf}[...]")
+            lines.append(f"{indent}{name} = {cp.buf}[...]{_UPCAST}")
             self.scope.bind(cid, name)
             return name
         return super().emit_value(cid, lines, indent)
@@ -423,26 +422,60 @@ class TileOp:
 
     ``pk=None`` marks a degraded op (Pallas emission failed under the
     guarded runtime): ``apply`` then delegates to ``jax_ref`` — the
-    kernel still runs, one ladder rung down (see docs/robustness.md)."""
+    kernel still runs, one ladder rung down (see docs/robustness.md).
+
+    ``row_block=None`` sizes the row block per call from the operands
+    that arrive (:meth:`block_rows`); an int pins it (the tuner's
+    candidates, tests)."""
     name: str
     pk: Optional[PallasKernel]
     jax_ref: Callable          # pure-jnp oracle built from the same program
-    row_block: int
+    row_block: Optional[int]
     source: str
     # full pipeline result the kernel was generated from — the timing/
     # calibration harness (benchmarks/measure.py) extracts its feature
     # vector from this exact extraction choice
     sk: Optional[Any] = None
 
+    @property
+    def n_tiles(self) -> int:
+        """Tiles one grid step holds: every operand plus two temporaries."""
+        return len(self.pk.in_arrays) + len(self.pk.out_arrays) + 2
+
+    def block_rows(self, d: int, dtype_bytes: int, chip=DEFAULT_CHIP) -> int:
+        """Row block for operands of feature width ``d`` stored at
+        ``dtype_bytes`` (the pinned ``row_block`` when one is set)."""
+        return self.row_block or pick_row_block(d, self.n_tiles,
+                                                dtype_bytes, chip)
+
     def __call__(self, *arrays, interpret: Optional[bool] = None, **scalars):
         return self.apply(*arrays, interpret=interpret, **scalars)
 
     def apply(self, *arrays, interpret: Optional[bool] = None, **scalars):
+        """Run the Pallas kernel. Differentiable: the backward pass is
+        ``jax.vjp`` of ``jax_ref``, the same math in jnp."""
         if self.pk is None:
             return self.jax_ref(*arrays, **scalars)
         interpret = _on_cpu() if interpret is None else interpret
-        return _apply_tile_op(self, arrays, tuple(sorted(scalars.items())),
-                              interpret)
+        names = tuple(sorted(scalars))
+
+        def ref(arrs, vals):
+            return self.jax_ref(*arrs, **dict(zip(names, vals)))
+
+        @jax.custom_vjp
+        def op(arrs, vals):
+            return _apply_tile_op(self, arrs, tuple(zip(names, vals)),
+                                  interpret)
+
+        def fwd(arrs, vals):
+            return op(arrs, vals), (arrs, vals)
+
+        def bwd(res, ct):
+            return jax.vjp(ref, *res)[1](ct)
+
+        op.defvjp(fwd, bwd)
+        vals = tuple(jnp.asarray(scalars[n], jnp.float32) for n in names)
+        return op(tuple(arrays), vals)
 
 
 def _row_index_map(i):
@@ -512,16 +545,16 @@ def plan_tile_call(pk: PallasKernel, in_shapes: Sequence[Tuple[int, ...]],
 
 def _apply_tile_op(op: TileOp, arrays, scalar_items, interpret: bool):
     pk = op.pk
-    # pipelined kernels carry a synchronous twin: interpret mode (and
-    # hosts without the TPU primitive set) run it — bit-identical to the
-    # "pallas" emitter — while the compiled path gets the async body
+    # pipelined kernels carry a synchronous twin: interpret mode runs it
+    # — bit-identical to the "pallas" emitter — while the compiled path
+    # gets the async body
     use_async = (pk.fallback_body is None
-                 or (not interpret and pltpu is not None
-                     and bool(pk.async_arrays)))
+                 or (not interpret and bool(pk.async_arrays)))
     body_fn = pk.kernel_body if use_async else pk.fallback_body
-    scalars = dict(scalar_items)
     lead = arrays[0]
-    plan = plan_tile_call(pk, [a.shape for a in arrays], op.row_block)
+    d = max(a.shape[-1] for a in arrays)
+    rb = op.block_rows(d, max(jnp.dtype(a.dtype).itemsize for a in arrays))
+    plan = plan_tile_call(pk, [a.shape for a in arrays], rb)
     rows, padded, d = plan.rows, plan.padded, plan.d
     ins2d = []
     for e, a in zip(plan.inputs, arrays):
@@ -532,32 +565,44 @@ def _apply_tile_op(op: TileOp, arrays, scalar_items, interpret: bool):
         else:  # broadcast weight (g, b, ...) — same block every row-tile
             a2 = a.reshape(1, -1)
         ins2d.append(a2)
-
-    def body(*refs):
-        body_fn(*refs, **scalars)
-
     in_specs = [pl.BlockSpec(e.block_shape, e.index_map)
                 for e in plan.inputs]
+    # scalars (eps, lr, ...) travel as one f32 SMEM operand after the
+    # tiles, so traced values work inside jit
+    names = [n for n, _ in scalar_items]
+    n_in = len(ins2d)
+    if names:
+        ins2d.append(jnp.stack([jnp.asarray(v, jnp.float32)
+                                for _, v in scalar_items]))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+
+    def body(*refs):
+        if not names:
+            return body_fn(*refs)
+        s_ref = refs[n_in]
+        body_fn(*refs[:n_in], *refs[n_in + 1:],
+                **{n: s_ref[i] for i, n in enumerate(names)})
+
     block_shapes = {e.name: e.block_shape for e in plan.inputs}
     out_specs = [pl.BlockSpec(e.block_shape, e.index_map)
                  for e in plan.outputs]
     out_shapes = [jax.ShapeDtypeStruct(e.buffer_shape, lead.dtype)
                   for e in plan.outputs]
-    scratch_shapes = None
+    scratch_shapes = ()
     if use_async and pk.async_arrays:
         # one VMEM staging buffer per pipelined input (block-shaped) plus
         # the two rotating DMA-completion semaphores
         scratch_shapes = [pltpu.VMEM(block_shapes[a], lead.dtype)
                           for a in pk.async_arrays]
         scratch_shapes += [pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA]
-    grid = plan.grid
     call = pl.pallas_call(
-        body, grid=grid, in_specs=in_specs,
+        body, grid=plan.grid, in_specs=in_specs,
         out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
         out_shape=out_shapes if len(out_shapes) > 1 else out_shapes[0],
-        **({"scratch_shapes": scratch_shapes}
-           if scratch_shapes is not None else {}),
-        interpret=interpret)
+        scratch_shapes=scratch_shapes,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=DEFAULT_CHIP.vmem_limit_bytes),
+        name=f"tile_{op.name}", interpret=interpret)
     outs = call(*ins2d)
     if not isinstance(outs, (tuple, list)):
         outs = (outs,)
@@ -571,30 +616,26 @@ def _ceil_to(x: int, m: int) -> int:
 
 def vmem_estimate(row_block: int, d: int, n_tiles: int,
                   dtype_bytes: int = 4) -> int:
-    """Conservative VMEM working-set estimate for a tile kernel.
-
-    A heuristic — ``n_tiles`` overcounts broadcast rows as full tiles.
-    The exact footprint (per-operand block shapes × double-buffer
-    multiplicity) lives in ``repro.verify.grid_check``, whose VMEM pass
-    flags configs where this estimate and the exact model disagree
-    (``vmem-heuristic-drift``)."""
-    return row_block * d * dtype_bytes * n_tiles
+    """VMEM one grid step of a tile kernel may hold: each of ``n_tiles``
+    ``(row_block, d)`` tiles twice at ``dtype_bytes`` (Pallas
+    double-buffers every blocked operand across grid steps) plus once
+    as the f32 value the body computes on. Conservative — broadcast rows
+    count as full tiles; ``repro.verify.grid_check`` holds the exact
+    double-buffered block footprint."""
+    return row_block * d * n_tiles * (2 * dtype_bytes + 4)
 
 
 def pick_row_block(d: int, n_tiles: int, dtype_bytes: int = 4,
                    chip=DEFAULT_CHIP) -> int:
-    """Largest row block (multiple of 8, ≤512) fitting the VMEM budget.
-
-    8 sublanes × 128 lanes is the fp32 native tile; we keep ~4x headroom
-    for temporaries the compiler materializes (the TPU analogue of the
-    paper's register-pressure concern, §VIII). ``dtype_bytes`` scales
-    the budget to the element width actually stored (bf16 tiles cost
-    half the VMEM of f32 — pass 2, not the f32 default)."""
-    budget = chip.vmem_bytes // 4
+    """Largest power-of-two row block (8..512) whose :func:`vmem_estimate`
+    fits ``chip.vmem_limit_bytes`` — the scoped-VMEM limit each tile
+    kernel requests from the compiler, and the budget the grid
+    verifier certifies against."""
     blk = 512
-    while blk > 8 and vmem_estimate(blk, d, n_tiles, dtype_bytes) > budget:
+    while blk > 8 and (vmem_estimate(blk, d, n_tiles, dtype_bytes)
+                       > chip.vmem_limit_bytes):
         blk //= 2
-    return max(blk, 8)
+    return blk
 
 
 def _declared_feature_dim(prog: KernelProgram) -> Optional[int]:
@@ -686,18 +727,11 @@ def make_tile_op(prog: KernelProgram,
         # (the saturated JAX kernel, or the reference interpreter when
         # the ladder bottomed out at "ref")
         return TileOp(name=prog.name, pk=None, jax_ref=jax_ref,
-                      row_block=row_block or 8,
+                      row_block=row_block,
                       source=sk.kernel.source, sk=sk)
 
-    n_tiles = len(pk.in_arrays) + len(pk.out_arrays) + 2
-    # autosize from the *declared* operand geometry: the feature width
-    # and element byte width the program actually stores, not the
-    # hardcoded (256, f32) the pre-PR-9 heuristic assumed — a d=1024
-    # f32 program now picks a smaller, VMEM-fitting block while bf16
-    # keeps the larger one its halved bytes afford
-    rb = row_block or pick_row_block(_declared_feature_dim(prog) or 256,
-                                     n_tiles, _declared_dtype_bytes(prog))
-    op = TileOp(name=prog.name, pk=pk, jax_ref=jax_ref, row_block=rb,
+    # row_block=None: sized per call from the operands that arrive
+    op = TileOp(name=prog.name, pk=pk, jax_ref=jax_ref, row_block=row_block,
                 source=pk.source, sk=sk)
     if cfg.verify != "off":
         # the grid pass (PR 9): statically certify the launch plan this

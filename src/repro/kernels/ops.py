@@ -13,12 +13,15 @@ globally (tests sweep all three).
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.telemetry import telemetry
+from repro.parallel import ctx
 from repro.runtime.guard import breaker_for
 
 from . import ref as _ref
@@ -112,13 +115,41 @@ def _guarded(name: str, optimized: Callable, reference: Callable):
     return out
 
 
+def _local(fn: Callable, arrays, specs, out_spec):
+    """Run a Pallas kernel on each device's shard under the active mesh.
+
+    The partitioner cannot split a Pallas kernel, so under a mesh the
+    call goes through ``jax.shard_map`` with explicit specs; without one
+    it runs as is."""
+    mesh = ctx.active_mesh()
+    if mesh is None:
+        return fn(*arrays)
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=out_spec, check_vma=False)(*arrays)
+
+
+def _pallas_tile(name: str, arrays, scalars):
+    """The Pallas tile op; under a mesh every device runs it over
+    replicated operands. Scalars travel as arguments so traced values
+    (the learning rate) cross into ``shard_map``."""
+    names = sorted(scalars)
+    n = len(arrays)
+
+    def run(*args):
+        return _op(name).apply(*args[:n], **dict(zip(names, args[n:])))
+
+    args = list(arrays) + [jnp.asarray(scalars[k], jnp.float32)
+                           for k in names]
+    return _local(run, args, (P(),) * len(args), P())
+
+
 def _tile(name: str, *arrays, **scalars):
     impl = current_impl()
     ref_fn = _REF_FNS[name]
     if impl == "ref":
         return ref_fn(*arrays, **scalars)
     if impl == "pallas":
-        return _guarded(name, lambda: _op(name).apply(*arrays, **scalars),
+        return _guarded(name, lambda: _pallas_tile(name, arrays, scalars),
                         lambda: ref_fn(*arrays, **scalars))
     return _guarded(name, lambda: _op(name).jax_ref(*arrays, **scalars),
                     lambda: ref_fn(*arrays, **scalars))
@@ -152,12 +183,11 @@ def rotary(q, cos, sin):
         return _ref.rotary_ref(q, cos, sin)
 
     def _opt():
-        op = _op("rotary")
         cosb = jnp.broadcast_to(cos, q.shape)
         sinb = jnp.broadcast_to(sin, q.shape)
         if impl == "pallas":
-            return op.apply(q, cosb, sinb)
-        return op.jax_ref(q, cosb, sinb)
+            return _pallas_tile("rotary", (q, cosb, sinb), {})
+        return _op("rotary").jax_ref(q, cosb, sinb)
 
     return _guarded("rotary", _opt, lambda: _ref.rotary_ref(q, cos, sin))
 
@@ -188,11 +218,10 @@ def ssd_gate(dt_raw, a_log, bias=0.0):
         return _ref.ssd_gate_ref(dt_raw, a_log, bias=bias)
 
     def _opt():
-        op = _op("ssd_gate")
         a_b = jnp.broadcast_to(a_log, dt_raw.shape)
         if impl == "pallas":
-            return op.apply(dt_raw, a_b, bias=bias)
-        return op.jax_ref(dt_raw, a_b, bias=bias)
+            return _pallas_tile("ssd_gate", (dt_raw, a_b), {"bias": bias})
+        return _op("ssd_gate").jax_ref(dt_raw, a_b, bias=bias)
 
     return _guarded("ssd_gate", _opt,
                     lambda: _ref.ssd_gate_ref(dt_raw, a_log, bias=bias))
@@ -202,10 +231,19 @@ def ssd_gate(dt_raw, a_log, bias=0.0):
 def attention(q, k, v, *, causal=True, scale=None, q_block=128,
               kv_block=128):
     impl = current_impl()
-    if impl == "pallas":
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               q_block=q_block, kv_block=kv_block)
-    return _ref.attention_ref(q, k, v, causal=causal, scale=scale)
+    if impl != "pallas":
+        return _ref.attention_ref(q, k, v, causal=causal, scale=scale)
+    spec = P()
+    mesh = ctx.active_mesh()
+    if mesh is not None and "model" in mesh.axis_names:
+        # heads are independent: each device attends over its own heads
+        tp = mesh.shape["model"]
+        if q.shape[1] == k.shape[1] and q.shape[1] % tp == 0:
+            spec = P(None, "model", None, None)
+    return _local(functools.partial(flash_attention, causal=causal,
+                                    scale=scale, q_block=q_block,
+                                    kv_block=kv_block),
+                  (q, k, v), (spec,) * 3, spec)
 
 
 def attention_decode(q, k_cache, v_cache, *, scale=None):

@@ -13,6 +13,15 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices):
+    """Auto-sharded axes: the partitioner propagates shardings through
+    gathers and scans (Explicit axes reject the embedding lookup's
+    inferred spec)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, have {len(devices)}; "
             f"run under launch/dryrun.py (XLA_FLAGS host device count) "
             f"or on a real pod slice")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return _mesh(shape, axes, devices[:need])
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
@@ -33,5 +42,4 @@ def make_debug_mesh(data: int = 1, model: int = 1):
     need = data * model
     devices = jax.devices()
     assert len(devices) >= need
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devices[:need])
+    return _mesh((data, model), ("data", "model"), devices[:need])

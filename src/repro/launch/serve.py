@@ -1,15 +1,18 @@
-"""Serving driver: batched prefill + decode with continuous batching.
+"""Serving driver: batched greedy prefill + decode.
 
-A minimal production-shaped server loop:
+A minimal server loop:
   * requests arrive with prompts of different lengths;
-  * scheduler packs up to ``max_batch`` active sequences;
-  * prefill runs per-admission, decode advances the whole batch one token
-    per tick via the jitted serve_step (the same function the decode
-    dry-run cells lower);
-  * finished sequences free their slot (continuous batching).
+  * ``generate`` takes them in fixed batches of up to ``max_batch``,
+    left-pads each batch's prompts to its longest, runs one prefill, then
+    advances the whole batch one token per tick via the jitted decode
+    step (the same function the decode dry-run cells lower) until the
+    batch's longest ``max_new`` is reached. There is no continuous
+    batching: a finished sequence keeps its slot until its batch ends.
 
 CPU-scale entry:
   PYTHONPATH=src python -m repro.launch.serve --arch mamba2-1.3b --smoke
+Full published width (on a chip):
+  PYTHONPATH=src python -m repro.launch.serve --arch minitron-4b --full
 """
 from __future__ import annotations
 
@@ -27,6 +30,7 @@ from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core import SaturatorConfig
 from repro.core.telemetry import telemetry
 from repro.kernels import ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 
 from repro.cache import default_cache_dir
@@ -62,7 +66,9 @@ class Server:
         arch = ARCH_IDS.get(arch, arch)
         self.cfg = get_smoke_config(arch) if smoke else get_config(arch)
         self.model = get_model(self.cfg)
-        self.params = self.model.init(jax.random.PRNGKey(seed))
+        # jitted: the random init fuses into bf16 outputs (no f32 copy of
+        # the largest weight alive at full width)
+        self.params = jax.jit(self.model.init)(jax.random.PRNGKey(seed))
         self.max_batch = max_batch
         self.max_seq = max_seq
         self._decode = jax.jit(self.model.decode_step)
@@ -88,7 +94,7 @@ class Server:
         return logits, cache
 
     def generate(self, requests: List[Request]) -> Dict[int, List[int]]:
-        """Serve a list of requests with continuous batching (greedy)."""
+        """Serve a list of requests greedily, ``max_batch`` at a time."""
         pending = list(requests)
         results: Dict[int, List[int]] = {}
         while pending:
@@ -127,6 +133,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minitron-4b")
     ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="the published config instead of the smoke one")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=8)
@@ -143,6 +151,7 @@ def main(argv=None):
     # one documented front door for the cache/verify side-channels:
     # explicit arg > CLI flag > env var (REPRO_SAT_CACHE / REPRO_VERIFY)
     sat = SaturatorConfig.from_env(flags=args)
+    enable_compile_cache()
     srv = Server(args.arch, smoke=args.smoke,
                  cache_dir=sat.cache_dir or None, verify=sat.verify)
     rng = np.random.default_rng(0)

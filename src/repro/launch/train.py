@@ -3,12 +3,12 @@
 CPU-scale entry (smoke/examples):
   PYTHONPATH=src python -m repro.launch.train --arch minitron-4b --smoke \\
       --steps 50 --batch 8 --seq 128
+Full published width (on a chip):
+  PYTHONPATH=src python -m repro.launch.train --arch whisper-small --full \\
+      --steps 4 --batch 8 --seq 256
 
-Production posture: the same loop drives the 16×16 / 2×16×16 meshes via
---mesh single|multi (requires a real pod or the dry-run device flag); the
-jitted step carries explicit shardings from repro.parallel, checkpointing
-is async+atomic, failures are recovered elastically, and the gradient
-all-reduce can be compressed (--compress bf16|int8_ef).
+Checkpointing is async+atomic, failures are recovered elastically, and
+the gradient exchange can be compressed (--compress bf16|int8_ef).
 """
 from __future__ import annotations
 
@@ -27,6 +27,7 @@ from repro.core import SaturatorConfig
 from repro.core.telemetry import telemetry
 from repro.data import DataConfig, ShardedTokenPipeline
 from repro.kernels import ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.optim import OptConfig, apply_updates, init_opt_state
 from repro.parallel.compression import Compressor
@@ -38,7 +39,8 @@ def build_trainer(arch: str, *, smoke: bool, steps: int, batch: int,
                   inject: Optional[dict] = None, lr: float = 3e-4,
                   num_shards: int = 1, seed: int = 0,
                   cache_dir: Optional[str] = None,
-                  verify: Optional[str] = None) -> ElasticTrainer:
+                  verify: Optional[str] = None,
+                  ckpt_every: Optional[int] = None) -> ElasticTrainer:
     # persist saturation results (norm/optimizer tile ops) across runs:
     # a restarted or elastically-recovered job replays committed kernels
     # instead of re-searching
@@ -54,7 +56,7 @@ def build_trainer(arch: str, *, smoke: bool, steps: int, batch: int,
     comp = Compressor(compress)
 
     rng = jax.random.PRNGKey(seed)
-    params = model.init(rng)
+    params = jax.jit(model.init)(rng)
     opt_state = init_opt_state(params, opt_cfg)
     comp_state = comp.init_state(params) if compress == "int8_ef" else None
 
@@ -88,8 +90,8 @@ def build_trainer(arch: str, *, smoke: bool, steps: int, batch: int,
 
         return step_np, pipe
 
-    loop_cfg = TrainLoopConfig(total_steps=steps, ckpt_every=max(steps // 4,
-                                                                 1),
+    loop_cfg = TrainLoopConfig(total_steps=steps,
+                               ckpt_every=ckpt_every or max(steps // 4, 1),
                                ckpt_dir=ckpt_dir)
     return ElasticTrainer(loop_cfg, build_step, params, opt_state,
                           num_shards=num_shards,
@@ -125,6 +127,7 @@ def main(argv=None):
     # one documented front door for the cache/verify side-channels:
     # explicit arg > CLI flag > env var (REPRO_SAT_CACHE / REPRO_VERIFY)
     sat = SaturatorConfig.from_env(flags=args)
+    enable_compile_cache()
     trainer = build_trainer(args.arch, smoke=args.smoke, steps=args.steps,
                             batch=args.batch, seq=args.seq,
                             ckpt_dir=args.ckpt_dir, lr=args.lr,

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.kernels import ops
+from repro.kernels.flash_attention import block_ids, blocked_attention_bwd
 from repro.parallel import ctx
 from .common import ModelConfig, dense_init, rope_cos_sin, split_keys
 
@@ -67,7 +68,8 @@ def _wq_padded(p, cfg, Hp):
 # ---------------------------------------------------------------------------
 # blocked attention (pure jnp, memory-bounded): the CPU/dry-run path.
 # Same online-softmax math as the Pallas flash kernel; flash-2 style
-# custom VJP recomputes block scores instead of saving (S x S) probs.
+# custom VJP (shared with the kernel: blocked_attention_bwd) recomputes
+# block scores instead of saving (S x S) probs.
 # ---------------------------------------------------------------------------
 def blocked_attention(q, k, v, *, causal=True, scale=None,
                       q_block=512, kv_block=512):
@@ -91,19 +93,13 @@ def _flash(q, k, v, causal, scale, q_block, kv_block):
     return o
 
 
-def _block_ids(nq, nk, q_block, kv_block):
-    qpos = lax.broadcasted_iota(jnp.int32, (q_block, kv_block), 0)
-    kpos = lax.broadcasted_iota(jnp.int32, (q_block, kv_block), 1)
-    return qpos, kpos
-
-
 def _flash_fwd_impl(q, k, v, causal, scale, q_block, kv_block):
     B, H, S, D = q.shape
     nq, nk = S // q_block, S // kv_block
     qb = q.reshape(B, H, nq, q_block, D).transpose(2, 0, 1, 3, 4)
     kb = k.reshape(B, H, nk, kv_block, D).transpose(2, 0, 1, 3, 4)
     vb = v.reshape(B, H, nk, kv_block, D).transpose(2, 0, 1, 3, 4)
-    qpos0, kpos0 = _block_ids(nq, nk, q_block, kv_block)
+    qpos0, kpos0 = block_ids(q_block, kv_block)
 
     def q_step(_, qi_and_q):
         qi, qt = qi_and_q
@@ -148,57 +144,7 @@ def _flash_fwd(q, k, v, causal, scale, q_block, kv_block):
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, q_block, kv_block, res, do):
-    q, k, v, o, lse = res
-    B, H, S, D = q.shape
-    nq, nk = S // q_block, S // kv_block
-    qb = q.reshape(B, H, nq, q_block, D).transpose(2, 0, 1, 3, 4)
-    kb = k.reshape(B, H, nk, kv_block, D).transpose(2, 0, 1, 3, 4)
-    vb = v.reshape(B, H, nk, kv_block, D).transpose(2, 0, 1, 3, 4)
-    dob = do.reshape(B, H, nq, q_block, D).transpose(2, 0, 1, 3, 4)
-    lseb = lse.reshape(B, H, nq, q_block).transpose(2, 0, 1, 3)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
-    deltab = delta.reshape(B, H, nq, q_block).transpose(2, 0, 1, 3)
-    qpos0, kpos0 = _block_ids(nq, nk, q_block, kv_block)
-
-    def kv_outer(_, ki_and_kv):
-        ki, kt, vt = ki_and_kv
-
-        def q_inner(carry, qi_pack):
-            dk_a, dv_a = carry
-            qi, qt, dot_, lse_i, delta_i = qi_pack
-            s = jnp.einsum("bhqd,bhkd->bhqk", qt, kt,
-                           preferred_element_type=jnp.float32) * scale
-            if causal:
-                msk = (qi * q_block + qpos0) >= (ki * kv_block + kpos0)
-                s = jnp.where(msk[None, None], s, -1e30)
-            pmat = jnp.exp(s - lse_i[..., None])
-            dp = jnp.einsum("bhqd,bhkd->bhqk", dot_.astype(jnp.float32),
-                            vt.astype(jnp.float32))
-            ds = pmat * (dp - delta_i[..., None]) * scale
-            dk_a = dk_a + jnp.einsum("bhqk,bhqd->bhkd", ds,
-                                     qt.astype(jnp.float32))
-            dv_a = dv_a + jnp.einsum("bhqk,bhqd->bhkd", pmat,
-                                     dot_.astype(jnp.float32))
-            dq_i = jnp.einsum("bhqk,bhkd->bhqd", ds, kt.astype(jnp.float32))
-            return (dk_a, dv_a), dq_i
-
-        B_, H_ = kt.shape[0], kt.shape[1]
-        init = (jnp.zeros((B_, H_, kv_block, D), jnp.float32),
-                jnp.zeros((B_, H_, kv_block, D), jnp.float32))
-        (dk_b, dv_b), dq_parts = lax.scan(
-            q_inner, init, (jnp.arange(nq), qb, dob, lseb, deltab))
-        return None, (dk_b, dv_b, dq_parts)
-
-    _, (dk_b, dv_b, dq_all) = lax.scan(kv_outer, None,
-                                       (jnp.arange(nk), kb, vb))
-    dq = dq_all.sum(0).transpose(1, 2, 0, 3, 4).reshape(B, H, S, D)
-    dk = dk_b.transpose(1, 2, 0, 3, 4).reshape(B, H, S, D)
-    dv = dv_b.transpose(1, 2, 0, 3, 4).reshape(B, H, S, D)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
-
-
-_flash.defvjp(_flash_fwd, _flash_bwd)
+_flash.defvjp(_flash_fwd, blocked_attention_bwd)
 
 
 def _naive(q, k, v, causal, scale):

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(mesh: Mesh, stage_fn: Callable, n_stages: int,
@@ -82,11 +81,11 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, n_stages: int,
         outs = lax.all_gather(outs, axis)[S - 1]
         return outs
 
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()),          # params sharded by stage; x replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x)
 
 

@@ -149,8 +149,8 @@ def verify_tile_op(op, rows: Optional[int] = None,
     """Certify one :class:`~repro.core.pallasgen.TileOp`'s launch plan
     (PR 9): the grid pass over exactly the :func:`plan_tile_call` plan
     the op executes — coverage, write disjointness, bounds with the pad
-    region modeled, exact VMEM fit, and the legacy-heuristic drift
-    comparison. Wired into ``make_tile_op`` for every ``verify`` level
+    region modeled, and the exact VMEM fit against the scoped limit.
+    Wired into ``make_tile_op`` for every ``verify`` level
     above ``"off"``; findings land in the process telemetry like every
     other pass."""
     kwargs = {} if chip is None else {"chip": chip}

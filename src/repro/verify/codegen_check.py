@@ -89,6 +89,15 @@ def _const_int(node: ast.expr) -> Optional[int]:
     return None
 
 
+def _strip_cast(node: ast.expr) -> ast.expr:
+    """``x.astype(dt)`` -> ``x``: the Pallas emitters upcast every load
+    and cast every store, which changes neither aliasing nor shape."""
+    while (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+           and node.func.attr == "astype"):
+        node = node.func.value
+    return node
+
+
 def _index_elts(sub: ast.Subscript) -> List[ast.expr]:
     sl = sub.slice
     return list(sl.elts) if isinstance(sl, ast.Tuple) else [sl]
@@ -141,6 +150,8 @@ def _loads_outside_nested(st: ast.stmt) -> List[ast.Name]:
     def walk(node: ast.AST):
         if isinstance(node, ast.FunctionDef) and node is not st:
             return
+        if isinstance(node, ast.Attribute) and node.attr == "dtype":
+            return    # ref metadata (the store cast), not a read
         if isinstance(node, ast.Name) and isinstance(node.ctx, ast.Load):
             found.append(node)
         for ch in ast.iter_child_nodes(node):
@@ -172,7 +183,7 @@ def _shape_env(fn: ast.FunctionDef,
             if tgt in env:
                 continue
             src: Optional[str] = None
-            val = node.value
+            val = _strip_cast(node.value)
             if isinstance(val, ast.Name):
                 src = val.id             # _v3 = x
             elif isinstance(val, ast.Subscript) and \
@@ -280,7 +291,7 @@ def _check_fn(fn: ast.FunctionDef, shapes: Dict[str, Shape],
                 if ref and ref.endswith("_oref"):
                     first_oref_write.setdefault(base, pos)
         elif isinstance(tgt, ast.Name) and _TEMP_RE.match(tgt.id):
-            val = st.value
+            val = _strip_cast(st.value)
             is_load = (isinstance(val, ast.Name)
                        and _base_array(val.id, shapes) is not None) or \
                       (isinstance(val, ast.Subscript)

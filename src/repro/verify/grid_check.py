@@ -21,11 +21,9 @@ proves, per kernel and per candidate configuration:
   *post-padding* (``_ceil_to``), so the pad region is modeled as
   in-bounds explicitly rather than waved at.
 * **VMEM budget** — the exact working set (block windows × double-buffer
-  multiplicity + scratch) fits chip VMEM: ``grid-vmem-overflow``.
-  :func:`check_tile_op` additionally compares the exact footprint
-  against the legacy ``vmem_estimate`` heuristic and emits a
-  ``vmem-heuristic-drift`` warning when the two disagree about fitting
-  the autosizing budget — the drift satellite of ISSUE 9.
+  multiplicity + scratch) fits ``chip.vmem_limit_bytes``, the scoped
+  VMEM limit the tile kernels request from the compiler and the budget
+  ``pick_row_block`` sizes against: ``grid-vmem-overflow``.
 
 Certification is exact set arithmetic when the grid is enumerable
 (≤ ``ENUM_LIMIT`` instances — every committed kernel) and falls back to
@@ -291,11 +289,12 @@ def check_grid(model: GridModel, chip=DEFAULT_CHIP) -> GridCheckResult:
                                     for f in findings)
 
     vb = model.vmem_bytes
-    if vb > chip.vmem_bytes:
+    if vb > chip.vmem_limit_bytes:
         findings.append(_f(
             "error", "grid-vmem-overflow", model.name,
             f"exact VMEM working set {vb} B (blocks x double-buffers + "
-            f"scratch) exceeds chip VMEM {chip.vmem_bytes} B"))
+            f"scratch) exceeds the scoped VMEM limit "
+            f"{chip.vmem_limit_bytes} B"))
     return GridCheckResult(findings=findings, grids_checked=1,
                            vmem_bytes=vb, provable=provable)
 
@@ -329,15 +328,15 @@ def tile_input_shapes(pk, prog, rows: int, d: int) -> List[Tuple[int, ...]]:
 def tile_call_model(pk, plan, dtype_bytes: int = 4,
                     pipelined: Optional[bool] = None) -> GridModel:
     """Convert one :func:`repro.core.pallasgen.plan_tile_call` plan into
-    the checkable :class:`GridModel`. ``pipelined`` doubles the VMEM
-    multiplicity of the kernel's ``async_plan`` arrays (block window +
-    staging scratch buffer); default = whether the kernel carries one."""
+    the checkable :class:`GridModel`. Every blocked operand is
+    double-buffered; ``pipelined`` adds the staging scratch buffer of the
+    kernel's ``async_plan`` arrays (default = whether it carries one)."""
     pipelined = bool(pk.async_arrays) if pipelined is None else pipelined
     async_set = set(pk.async_arrays) if pipelined else set()
     reads = tuple(
         BlockAccess(e.name, "read", e.block_shape, e.buffer_shape,
                     e.index_map, dtype_bytes=dtype_bytes,
-                    buffers=2 if e.name in async_set else 1)
+                    buffers=3 if e.name in async_set else 2)
         for e in plan.inputs)
     writes = tuple(
         BlockAccess(e.name, "write", e.block_shape, e.buffer_shape,
@@ -351,53 +350,26 @@ def check_tile_kernel_grid(pk, prog, row_block: Optional[int] = None,
                            d: Optional[int] = None,
                            chip=DEFAULT_CHIP) -> GridCheckResult:
     """Certify one emitted :class:`~repro.core.pallasgen.PallasKernel`'s
-    launch plan at a given ``row_block`` (default: what ``make_tile_op``
-    would autosize from the declared geometry).
+    launch plan at a given ``row_block`` (default: what
+    ``pick_row_block`` sizes for the audited width and declared dtype).
 
     ``rows`` defaults to a geometry that exercises the padded remainder
     tile (``rows % row_block != 0``); ``d`` to the program's declared
-    feature width. On top of :func:`check_grid`, compares the exact
-    footprint with the legacy ``vmem_estimate(row_block, 256, n_tiles,
-    4)`` heuristic and reports ``vmem-heuristic-drift`` when they
-    disagree about fitting the autosizing budget (suppressed when the
-    hard overflow already fired — the error subsumes the drift note)."""
+    feature width."""
     from repro.core.pallasgen import (_declared_dtype_bytes,
                                       _declared_feature_dim,
-                                      pick_row_block, plan_tile_call,
-                                      vmem_estimate)
-    n_tiles = len(pk.in_arrays) + len(pk.out_arrays) + 2
-    rb = row_block or (pick_row_block(
-        (_declared_feature_dim(prog) if prog is not None else None) or 256,
-        n_tiles,
-        _declared_dtype_bytes(prog) if prog is not None else 4,
-        chip=chip))
+                                      pick_row_block, plan_tile_call)
     if d is None:
         d = (_declared_feature_dim(prog) if prog is not None else None) \
             or 256
+    dtype_bytes = _declared_dtype_bytes(prog) if prog is not None else 4
+    n_tiles = len(pk.in_arrays) + len(pk.out_arrays) + 2
+    rb = row_block or pick_row_block(d, n_tiles, dtype_bytes, chip=chip)
     if rows is None:
         rows = 2 * rb + max(1, rb // 2)   # forces a ragged remainder tile
-    dtype_bytes = _declared_dtype_bytes(prog) if prog is not None else 4
     plan = plan_tile_call(pk, tile_input_shapes(pk, prog, rows, d), rb)
-    model = tile_call_model(pk, plan, dtype_bytes=dtype_bytes)
-    res = check_grid(model, chip)
-
-    overflow = any(f.code == "grid-vmem-overflow" for f in res.findings)
-    if not overflow:
-        legacy = vmem_estimate(plan.row_block, 256, n_tiles, 4)
-        budget = chip.vmem_bytes // 4
-        legacy_fits, exact_fits = legacy <= budget, res.vmem_bytes <= budget
-        if legacy_fits != exact_fits:
-            verdict = ("under-budgeted: the heuristic admits a config "
-                       "whose exact footprint busts the autosizing budget"
-                       if legacy_fits else
-                       "over-budgeted: the heuristic rejects a config "
-                       "whose exact footprint fits")
-            res.findings.append(_f(
-                "warning", "vmem-heuristic-drift", model.name,
-                f"legacy vmem_estimate {legacy} B vs exact "
-                f"{res.vmem_bytes} B against budget {budget} B — "
-                f"{verdict}"))
-    return res
+    return check_grid(tile_call_model(pk, plan, dtype_bytes=dtype_bytes),
+                      chip)
 
 
 def check_tile_op(op, rows: Optional[int] = None, d: Optional[int] = None,
@@ -423,8 +395,8 @@ def flash_attention_model(B: int, H: int, KH: int, S: int, D: int,
                            min(kv_block, S))
     reads = tuple(BlockAccess(n, "read", *lay[n], dtype_bytes=dtype_bytes)
                   for n in ("q", "k", "v"))
-    writes = (BlockAccess("o", "write", *lay["o"],
-                          dtype_bytes=dtype_bytes),)
+    writes = (BlockAccess("o", "write", *lay["o"], dtype_bytes=dtype_bytes),
+              BlockAccess("lse", "write", *lay["lse"], dtype_bytes=4))
     return GridModel("flash_attention", lay["grid"], reads, writes,
                      scratch_bytes=lay["scratch_bytes"])
 

@@ -63,7 +63,8 @@ from repro.parallel import batch_specs, ctx, param_specs, to_named
 from repro.launch import steps as S
 
 mesh = jax.make_mesh((2, 4), ("data", "model"),
-                     devices=jax.devices()[:8])
+                     devices=jax.devices()[:8],
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_smoke_config("minitron_4b")
 model = get_model(cfg)
 with ctx.activate(mesh):
@@ -92,7 +93,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.parallel.pipeline_pp import (make_stage_fn, pipeline_apply,
                                         split_layers_to_stages)
 
-mesh = jax.make_mesh((4,), ("stage",), devices=jax.devices()[:4])
+mesh = jax.make_mesh((4,), ("stage",), devices=jax.devices()[:4],
+                     axis_types=(jax.sharding.AxisType.Auto,))
 L, D, M, mb = 8, 16, 6, 4
 key = jax.random.PRNGKey(0)
 ws = jax.random.normal(key, (L, D, D)) * 0.3

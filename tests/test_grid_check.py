@@ -1,7 +1,7 @@
 """PR-9 grid/block legality pass: mutation tests (one planted defect →
 exactly one finding of exactly that code), the clean-suite zero-finding
-sweep, dtype-aware row-block autosizing, the legacy-heuristic drift
-detector, and a property fuzz asserting that every certified (rows,
+sweep, dtype-aware row-block autosizing against the scoped VMEM limit,
+and a property fuzz asserting that every certified (rows,
 row_block) geometry executes bit-identically to the unblocked baseline."""
 import dataclasses
 
@@ -104,9 +104,9 @@ def test_handwritten_layouts_certify_clean():
 
 # -- satellite 1+2: declared-geometry, dtype-aware autosizing -----------------
 def _wide_prog(name, dtype):
-    """4 in + 3 out at d=1024: 9 heuristic tiles, so a 512 row block
-    costs 512*1024*4B*9 = 18.9 MB f32 — past the 16 MiB autosizing
-    budget — but only 9.4 MB in bf16."""
+    """4 in + 3 out at d=1024: 9 tiles, so a 512 row block is estimated
+    at 512*1024*9*(2*4+4) B = 54 MiB in f32 — past the 48 MiB scoped
+    limit — but 36 MiB in bf16."""
     p = KernelProgram(name, dtype=dtype)
     a = p.array_in("a", shape=(8, 1024), dtype=dtype)
     b = p.array_in("b", shape=(8, 1024), dtype=dtype)
@@ -129,32 +129,31 @@ def test_pick_row_block_is_dtype_aware():
 
 
 def test_d1024_program_autosizes_smaller_block():
-    """Regression for the hardcoded d=256 in make_tile_op: a d=1024 f32
-    program must pick the VMEM-fitting 256, not the blanket 512 — and
-    its certified exact footprint must fit the autosizing budget."""
+    """A d=1024 f32 call must get the VMEM-fitting 256, not the blanket
+    512 — and its certified exact footprint must fit the scoped limit."""
     op = make_tile_op(_wide_prog("wide1024_f32", "f32"))
-    assert op.row_block == 256
+    assert op.row_block is None               # sized per call
+    assert op.block_rows(1024, 4) == 256
     res = check_tile_op(op)
     assert [f for f in res.findings if f.severity == "error"] == []
 
 
 def test_d1024_bf16_program_keeps_large_block():
     op = make_tile_op(_wide_prog("wide1024_bf16", "bf16"))
-    assert op.row_block == 512
+    assert op.block_rows(1024, 2) == 512
 
 
-# -- satellite 2: legacy heuristic drift --------------------------------------
-def test_vmem_heuristic_drift_flagged():
-    """At row_block=768 the wide f32 program's exact footprint
-    (768*1024*4B*7 = 22 MB) busts the 16 MiB budget, while the legacy
-    d=256 estimate (7.1 MB) says it fits: exactly one under-budgeted
-    drift warning, and no hard overflow (22 MB < 64 MiB VMEM)."""
-    op = make_tile_op(_wide_prog("wide1024_drift", "f32"))
-    res = check_tile_kernel_grid(op.pk, op.sk.ssa.prog, row_block=768)
-    assert _codes(res) == ["vmem-heuristic-drift"]
-    (w,) = res.findings
-    assert w.severity == "warning" and "under-budgeted" in w.message
-    assert res.ok     # warnings don't fail certification
+def test_vmem_pass_budgets_the_scoped_limit():
+    """The grid pass certifies against the same scoped limit the picker
+    sizes for: at row_block=1024 the wide f32 program's double-buffered
+    blocks (1024*1024*4B*2*7 = 56 MiB) overflow the 48 MiB limit — one
+    error — while the picked 256 certifies clean."""
+    op = make_tile_op(_wide_prog("wide1024_limit", "f32"))
+    res = check_tile_kernel_grid(op.pk, op.sk.ssa.prog, row_block=1024)
+    assert _codes(res) == ["grid-vmem-overflow"]
+    assert "limit 50331648 B" in res.findings[0].message
+    res = check_tile_kernel_grid(op.pk, op.sk.ssa.prog)
+    assert res.findings == [] and res.vmem_bytes <= 48 * 1024 ** 2
 
 
 # -- wiring: make_tile_op + telemetry -----------------------------------------
