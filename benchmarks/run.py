@@ -10,7 +10,6 @@ committed by accident). Tables:
   measure           — measured per-instance kernel times (the calibration
                       harness, benchmarks/measure.py) vs the roofline
                       model's predictions
-  lm_step           — framework train/decode step per architecture
 (The Tables II/III inventory — suite × sizes — is the kernel_suite itself;
 the dry-run roofline table lives in experiments/dryrun/.)
 
@@ -33,7 +32,6 @@ def main() -> None:
         from benchmarks.ablation import run_ablation
         from benchmarks.breakdown import run_breakdown
         from benchmarks.saturation_stats import run_saturation_stats
-        from benchmarks.lm_step import run_lm_step
         from benchmarks.measure import measure_all
     except ImportError as e:
         die_with_import_help(e)
@@ -85,14 +83,6 @@ def main() -> None:
         print(f"measure/{row['kernel']},{row['measured_ns']/1e3:.3f},"
               f"kind={row['measured_kind']};"
               f"predicted_ns={row['predicted_ns']:.1f}")
-
-    lm = run_lm_step()
-    (OUT / "lm_step.json").write_text(json.dumps(lm, indent=1))
-    for row in lm:
-        print(f"lm_step/{row['arch']}/train,{row['train_step_ms']*1e3:.1f},"
-              f"ms={row['train_step_ms']:.1f}")
-        print(f"lm_step/{row['arch']}/decode,{row['decode_step_ms']*1e3:.1f},"
-              f"ms={row['decode_step_ms']:.1f}")
 
 
 if __name__ == '__main__':

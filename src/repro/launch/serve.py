@@ -25,6 +25,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core import SaturatorConfig
@@ -76,11 +77,22 @@ class Server:
         # generate() calls are supported, so counter updates take this
         # lock (prevents lost increments / torn read-modify-write)
         self._metrics_lock = threading.Lock()
-        self.metrics = {"prefills": 0, "decode_ticks": 0, "tokens": 0}
+        self.metrics = {"prefills": 0, "decode_ticks": 0, "tokens": 0,
+                        "host_syncs": 0}
 
     def _bump(self, key: str, n: int = 1):
         with self._metrics_lock:
             self.metrics[key] += n
+
+    def _read_tokens(self, batch: List[Request], tok):
+        """Append this tick's token to every unfinished request: one
+        device-to-host read each."""
+        unfinished = [i for i, r in enumerate(batch)
+                      if len(r.out) < r.max_new]
+        with TraceAnnotation("repro.serve.readback", syncs=len(unfinished)):
+            for i in unfinished:
+                batch[i].out.append(int(tok[i, 0]))
+        self._bump("host_syncs", len(unfinished))
 
     def _prefill_batch(self, prompts: np.ndarray):
         tokens = jnp.asarray(prompts, jnp.int32)
@@ -94,7 +106,11 @@ class Server:
         return logits, cache
 
     def generate(self, requests: List[Request]) -> Dict[int, List[int]]:
-        """Serve a list of requests greedily, ``max_batch`` at a time."""
+        """Serve a list of requests greedily, ``max_batch`` at a time.
+
+        Host spans, recorded only while a profiler runs, mark each
+        batch's prefill (``repro.serve.prefill``) and each readback of
+        tokens to the host (``repro.serve.readback``)."""
         pending = list(requests)
         results: Dict[int, List[int]] = {}
         while pending:
@@ -103,20 +119,19 @@ class Server:
             plen = max(len(r.prompt) for r in batch)
             prompts = np.stack([
                 np.pad(r.prompt, (plen - len(r.prompt), 0)) for r in batch])
-            logits, cache = self._prefill_batch(prompts)
-            tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+            with TraceAnnotation("repro.serve.prefill", batch=len(batch),
+                                 seq=plen):
+                logits, cache = self._prefill_batch(prompts)
+                tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
             steps = max(r.max_new for r in batch)
             for t in range(steps - 1):
-                for i, r in enumerate(batch):
-                    if len(r.out) < r.max_new:
-                        r.out.append(int(tok[i, 0]))
+                self._read_tokens(batch, tok)
                 logits, cache = self._decode(self.params, cache, tok)
                 tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
                 self._bump("decode_ticks")
                 self._bump("tokens", len(batch))
-            for i, r in enumerate(batch):
-                if len(r.out) < r.max_new:
-                    r.out.append(int(tok[i, 0]))
+            self._read_tokens(batch, tok)
+            for r in batch:
                 r.done = True
                 results[r.rid] = r.out
         snap = telemetry().snapshot()
@@ -168,7 +183,8 @@ def main(argv=None):
     print(f"arch={args.arch} served {len(out)} requests, "
           f"{srv.metrics['tokens']} tokens in {dt:.1f}s "
           f"({srv.metrics['prefills']} prefills, "
-          f"{srv.metrics['decode_ticks']} ticks)")
+          f"{srv.metrics['decode_ticks']} ticks, "
+          f"{srv.metrics['host_syncs']} host syncs)")
     print(f"  saturation cache: hits={sat.get('cache_hits', 0)} "
           f"warm={sat.get('cache_warm_starts', 0)} "
           f"misses={sat.get('cache_misses', 0)} "

@@ -189,6 +189,7 @@ def _merge_heads(x):
     return x.transpose(0, 2, 1, 3).reshape(B, S, H * D)
 
 
+@jax.named_scope("attn")
 def attn_apply(p, x, cos, sin, cfg: ModelConfig, *, causal=True,
                kv_x: Optional[jnp.ndarray] = None):
     """Full-sequence attention. kv_x (encoder states) enables cross-attn."""
@@ -207,6 +208,7 @@ def attn_apply(p, x, cos, sin, cfg: ModelConfig, *, causal=True,
     return _merge_heads(o) @ wo
 
 
+@jax.named_scope("attn")
 def attn_prefill(p, x, cos, sin, cfg: ModelConfig):
     """Returns (out, (k_cache, v_cache)) for subsequent decode."""
     Hp = _padded_H(cfg)
@@ -224,6 +226,7 @@ def attn_prefill(p, x, cos, sin, cfg: ModelConfig):
     return _merge_heads(o) @ wo, kv_cache
 
 
+@jax.named_scope("attn")
 def attn_decode(p, x1, kv_cache, pos, cfg: ModelConfig,
                 cos1=None, sin1=None):
     """One-token decode. x1:(B,1,D); kv_cache: (k,v) each (B,KH,S,hd);

@@ -142,9 +142,11 @@ class ElasticTrainer:
                 batch = pipeline.batch_at(self.step)
                 self.params, self.opt_state, loss = step_fn(
                     self.params, self.opt_state, batch)
-                dt = time.perf_counter() - t0
-                self._track_straggler(dt)
-                self.losses.append(float(loss))
+                # timed once the loss is on the host: the step itself, not
+                # the time it took to enqueue it
+                loss = float(loss)
+                self._track_straggler(time.perf_counter() - t0)
+                self.losses.append(loss)
                 if (self.step + 1) % self.cfg.ckpt_every == 0:
                     self._checkpoint()
                 self.step += 1

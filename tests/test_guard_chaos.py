@@ -2,6 +2,7 @@
 circuit breaker, deterministic chaos harness, cache-fault hardening,
 straggler policy, and elastic-recovery state preservation."""
 import threading
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -389,6 +390,29 @@ def test_straggler_policy_tracking(tmp_path):
     tr._track_straggler(0.12)           # fast again: EWMA moves
     assert tr._ewma_time == pytest.approx(0.9 * 0.1 + 0.1 * 0.12)
     assert sum(1 for e in tr.log if e["straggler"]) == 2
+
+
+def test_straggler_clock_covers_the_loss_sync(tmp_path):
+    class SlowLoss:                     # reaches the host 20 ms late
+        def __float__(self):
+            time.sleep(0.02)
+            return 1.0
+
+    def build_step(n_shards):
+        class Pipe:
+            def batch_at(self, step):
+                return {}
+
+        return (lambda params, opt, batch: (params, opt, SlowLoss())), Pipe()
+
+    cfg = TrainLoopConfig(total_steps=3, ckpt_every=100,
+                          ckpt_dir=str(tmp_path / "ckpt"))
+    tr = ElasticTrainer(cfg, build_step, np.zeros(2, np.float32),
+                        {"m": np.zeros(2, np.float32)}, num_shards=1)
+    assert tr.run()["losses"] == [1.0] * 3
+    # the first step seeds the EWMA; the others are logged
+    assert len(tr.log) == 2
+    assert all(e["dt"] >= 0.02 for e in tr.log)
 
 
 def test_recovery_preserves_saturation_settings(tmp_path):

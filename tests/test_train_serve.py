@@ -75,3 +75,14 @@ def test_serve_greedy_deterministic():
     r1 = srv.generate([Request(0, prompt.copy(), 5)])
     r2 = srv.generate([Request(0, prompt.copy(), 5)])
     assert r1[0] == r2[0]
+
+
+def test_serve_counts_host_syncs():
+    srv = Server("minitron-4b", smoke=True, max_batch=2)
+    rng = np.random.default_rng(2)
+    reqs = [Request(i, rng.integers(1, srv.cfg.vocab, size=8)
+                    .astype(np.int32), n) for i, n in enumerate((3, 1, 2))]
+    out = srv.generate(reqs)
+    # one device-to-host read per token served
+    assert srv.metrics["host_syncs"] == sum(map(len, out.values())) == 6
+    assert srv.metrics["decode_ticks"] == 2 + 1
