@@ -3,7 +3,8 @@
 One tiny registry counts what the saturation subsystem actually did at
 runtime — persistent-cache hits / misses / warm starts with their wall
 times, and jaxpr-bridge fallbacks per unsupported primitive (the
-coverage gaps ``maybe_saturate`` used to swallow silently). It has no
+coverage gaps ``maybe_saturate`` used to swallow silently), and the
+tiles of each traced flash-attention launch. It has no
 dependencies so every layer (core pipeline, cache store, jaxpr bridge,
 launch drivers, benchmarks) can report into the same counters without
 import cycles.
@@ -63,6 +64,9 @@ class SaturationTelemetry:
     runtime_fallbacks: Dict[str, int] = dataclasses.field(
         default_factory=dict)   # kernel -> ops-layer ref-fallback count
     elastic_recoveries: int = 0
+    # hand-written kernel launches (repro.kernels.flash_attention)
+    flash_tiles: Dict[str, int] = dataclasses.field(
+        default_factory=dict)   # "<q_block>x<kv_block>" -> traced launches
     events: Deque[Dict[str, Any]] = dataclasses.field(
         default_factory=lambda: deque(maxlen=EVENT_LIMIT))
 
@@ -174,6 +178,16 @@ class SaturationTelemetry:
             self.events.append({"kind": "elastic_recovery", "step": step,
                                 "event": kind, "shards": shards})
 
+    # -- kernel launches ----------------------------------------------------
+    def record_flash_tiles(self, q_shape, q_block: int, kv_block: int):
+        """One traced flash-attention launch: its tiles, and the q shape
+        (B, H, S, D) that chose them."""
+        key = f"{q_block}x{kv_block}"
+        with self._lock:
+            self.flash_tiles[key] = self.flash_tiles.get(key, 0) + 1
+            self.events.append({"kind": "flash_tiles", "tiles": key,
+                                "q_shape": tuple(q_shape)})
+
     # -- reporting ----------------------------------------------------------
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
@@ -217,6 +231,7 @@ class SaturationTelemetry:
                         self.runtime_fallbacks.items())),
                     "elastic_recoveries": self.elastic_recoveries,
                 },
+                "flash_tiles": dict(sorted(self.flash_tiles.items())),
             }
 
     def reset(self):
@@ -238,6 +253,7 @@ class SaturationTelemetry:
             self.chaos_fires.clear()
             self.runtime_fallbacks.clear()
             self.elastic_recoveries = 0
+            self.flash_tiles.clear()
             self.events.clear()
 
 

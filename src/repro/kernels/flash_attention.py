@@ -9,13 +9,20 @@ traffic of each step.
 Grid: (batch*heads, q_blocks, kv_blocks) with kv innermost so the (m, l,
 acc) scratch carries across the kv sweep of one q tile.
 
+Tiles: unless the caller passes them, ``q_block = kv_block =``
+:func:`attention_tiles` of the sequence length: the largest of 512, 256
+and 128 that divides S, or S itself when S <= 128. A fixed 128 tile
+spends most of each grid step on per-step overhead rather than MXU work
+and re-reads K/V from HBM once per q block; the larger tile cuts both.
+
 Validated against :func:`repro.kernels.ref.attention_ref` in interpret
 mode (CPU) over shape/dtype sweeps; on TPU the same kernel compiles with
 MXU-aligned tiles (q_block × head_dim multiples of (8, 128)).
 
 Differentiable: the kernel also writes each row's log-sum-exp, and the
 custom VJP runs :func:`blocked_attention_bwd`, the flash-2 backward in
-jnp that the blocked CPU path (``models.layers``) shares.
+jnp that the blocked CPU path (``models.layers``) shares. The backward
+tiles its block scan with the forward's tiles.
 """
 from __future__ import annotations
 
@@ -29,7 +36,22 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.telemetry import telemetry
+
 NEG_INF = -1e30
+TILE_SIZES = (512, 256, 128)
+
+
+def attention_tiles(S: int) -> int:
+    """The square flash tile for a sequence of ``S``: the largest of
+    :data:`TILE_SIZES` that divides S, or S itself when S <= 128."""
+    if S <= TILE_SIZES[-1]:
+        return S
+    for t in TILE_SIZES:
+        if S % t == 0:
+            return t
+    raise ValueError(f"flash attention needs S <= 128 or a multiple of "
+                     f"128, got S={S}")
 
 
 def attention_layout(B: int, H: int, KH: int, S: int, D: int,
@@ -132,17 +154,24 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                                              "kv_block", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None,
-                    q_block: int = 128, kv_block: int = 128,
+                    q_block: Optional[int] = None,
+                    kv_block: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """q:(B,H,S,D) k/v:(B,KH,S,D) → (B,H,S,D). GQA when KH < H.
-    Interpret mode only on the CPU backend."""
+    Interpret mode only on the CPU backend.
+
+    A tile left as ``None`` is :func:`attention_tiles` of S; an explicit
+    tile wins (capped at S). The backward runs with the same tiles. Each
+    trace records its geometry in ``telemetry().snapshot()
+    ["flash_tiles"]``."""
     S, D = q.shape[2], q.shape[3]
     scale = (D ** -0.5) if scale is None else scale
     interpret = (jax.default_backend() == "cpu") if interpret is None \
         else interpret
-    q_block = min(q_block, S)
-    kv_block = min(kv_block, S)
+    q_block = min(q_block or attention_tiles(S), S)
+    kv_block = min(kv_block or attention_tiles(S), S)
     assert S % q_block == 0 and S % kv_block == 0
+    telemetry().record_flash_tiles(q.shape, q_block, kv_block)
     return _flash(q, k, v, causal, scale, q_block, kv_block, interpret)
 
 

@@ -228,8 +228,11 @@ def ssd_gate(dt_raw, a_log, bias=0.0):
 
 
 # -- structured kernels -----------------------------------------------------------
-def attention(q, k, v, *, causal=True, scale=None, q_block=128,
-              kv_block=128):
+def attention(q, k, v, *, causal=True, scale=None):
+    """Full-sequence attention. Under ``pallas`` it is
+    :func:`flash_attention` at the kernel's own tiles,
+    ``attention_tiles(S)``: the largest of 512, 256 and 128 that divides
+    S, or S when S <= 128. The backward follows the forward's tiles."""
     impl = current_impl()
     if impl != "pallas":
         return _ref.attention_ref(q, k, v, causal=causal, scale=scale)
@@ -241,8 +244,7 @@ def attention(q, k, v, *, causal=True, scale=None, q_block=128,
         if q.shape[1] == k.shape[1] and q.shape[1] % tp == 0:
             spec = P(None, "model", None, None)
     return _local(functools.partial(flash_attention, causal=causal,
-                                    scale=scale, q_block=q_block,
-                                    kv_block=kv_block),
+                                    scale=scale),
                   (q, k, v), (spec,) * 3, spec)
 
 

@@ -386,13 +386,18 @@ def check_tile_op(op, rows: Optional[int] = None, d: Optional[int] = None,
 
 
 def flash_attention_model(B: int, H: int, KH: int, S: int, D: int,
-                          q_block: int = 128, kv_block: int = 128,
+                          q_block: Optional[int] = None,
+                          kv_block: Optional[int] = None,
                           dtype_bytes: int = 4) -> GridModel:
     """The hand-written flash-attention launch as a checkable model
-    (shared layout: :func:`repro.kernels.flash_attention.attention_layout`)."""
-    from repro.kernels.flash_attention import attention_layout
-    lay = attention_layout(B, H, KH, S, D, min(q_block, S),
-                           min(kv_block, S))
+    (shared layout: :func:`repro.kernels.flash_attention.attention_layout`).
+    Tiles left as ``None`` are the kernel's own choice,
+    :func:`~repro.kernels.flash_attention.attention_tiles` of S."""
+    from repro.kernels.flash_attention import attention_layout, \
+        attention_tiles
+    lay = attention_layout(B, H, KH, S, D,
+                           min(q_block or attention_tiles(S), S),
+                           min(kv_block or attention_tiles(S), S))
     reads = tuple(BlockAccess(n, "read", *lay[n], dtype_bytes=dtype_bytes)
                   for n in ("q", "k", "v"))
     writes = (BlockAccess("o", "write", *lay["o"], dtype_bytes=dtype_bytes),

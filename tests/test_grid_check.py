@@ -6,6 +6,7 @@ row_block) geometry executes bit-identically to the unblocked baseline."""
 import dataclasses
 
 import numpy as np
+import pytest
 
 from _hypothesis_compat import given, settings, st
 from repro.analysis.access import BlockAccess, GridModel
@@ -100,6 +101,19 @@ def test_handwritten_layouts_certify_clean():
         res = check_grid(model)
         assert res.findings == [], [str(f) for f in res.findings]
         assert res.vmem_bytes > 0
+
+
+@pytest.mark.parametrize("B,H,KH,S,D", [(4, 24, 8, 2048, 128),
+                                         (16, 12, 12, 512, 64)],
+                         ids=("minitron_prefill", "whisper_train"))
+def test_flash_model_certifies_the_cells_chosen_tiles(B, H, KH, S, D):
+    """With no tiles given the model takes the kernel's own choice (512
+    at both cells' lengths), so the verifier certifies what runs."""
+    model = flash_attention_model(B, H, KH, S, D, dtype_bytes=2)
+    assert model.grid == (B * H, S // 512, S // 512)
+    res = check_grid(model)
+    assert res.findings == [], [str(f) for f in res.findings]
+    assert res.provable
 
 
 # -- satellite 1+2: declared-geometry, dtype-aware autosizing -----------------

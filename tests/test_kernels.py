@@ -7,7 +7,8 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention
+from repro.core.telemetry import telemetry
+from repro.kernels.flash_attention import attention_tiles, flash_attention
 from repro.kernels.ssd_scan import ssd_scan, ssd_scan_jnp, ssd_decode_step
 from repro.kernels.tile_programs import PROGRAMS, get_tile_op
 
@@ -136,6 +137,74 @@ def test_flash_attention_bf16(rng):
                              v.astype(jnp.float32), causal=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want), atol=5e-2, rtol=5e-2)
+
+
+@pytest.mark.parametrize("S,tile", [(64, 64), (128, 128), (384, 128),
+                                    (512, 512), (768, 256), (2048, 512),
+                                    (4096, 512)])
+def test_attention_tiles(S, tile):
+    assert attention_tiles(S) == tile
+    assert S % tile == 0
+
+
+def test_attention_tiles_refuses_a_ragged_sequence():
+    with pytest.raises(ValueError, match="multiple of 128"):
+        attention_tiles(200)
+
+
+FLASH_512 = {"causal": (4, 4, True), "noncausal": (4, 4, False),
+             "gqa": (4, 2, True)}
+
+
+def _qkv(rng, H, KH, S=512, D=128):
+    return tuple(jnp.asarray(rng.normal(size=(1, h, S, D)), jnp.float32)
+                 for h in (H, KH, KH))
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_512))
+def test_flash_attention_chosen_tiles_s512(case, rng):
+    """No tiles given: one 512x512 tile over the whole sequence."""
+    H, KH, causal = FLASH_512[case]
+    q, k, v = _qkv(rng, H, KH)
+    out = flash_attention(q, k, v, causal=causal)
+    want = ref.attention_ref(q, k, v, causal=causal)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_512))
+def test_flash_attention_grad_chosen_tiles_s512(case, rng):
+    """The backward runs with the forward's (chosen) tiles."""
+    H, KH, causal = FLASH_512[case]
+    q, k, v = _qkv(rng, H, KH)
+    w = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, causal=causal) * w)
+
+    got = jax.grad(loss(flash_attention), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref.attention_ref), (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("S,key", [(512, "512x512"), (128, "128x128")])
+def test_flash_tiles_recorded_in_telemetry(S, key):
+    """A traced launch records its tiles; the shape (an odd head count
+    no other test uses) keeps jit's trace cache from hiding the call."""
+    q = jax.ShapeDtypeStruct((1, 3, S, 8), jnp.float32)
+    before = telemetry().snapshot()["flash_tiles"].get(key, 0)
+    ops.set_impl("pallas")
+    try:
+        jax.eval_shape(lambda q: ops.attention(q, q, q), q)
+    finally:
+        ops.set_impl(None)
+    assert telemetry().snapshot()["flash_tiles"].get(key, 0) == before + 1
+    last = [e for e in telemetry().events if e["kind"] == "flash_tiles"][-1]
+    assert last == {"kind": "flash_tiles", "tiles": key,
+                    "q_shape": (1, 3, S, 8)}
 
 
 def test_decode_attention_matches_full(rng):

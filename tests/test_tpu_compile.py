@@ -51,10 +51,19 @@ def _flash(q, k, v):
     return flash_attention(q, k, v, causal=True, interpret=False)
 
 
-def _flash_grad(q, k, v):
-    def loss(q, k, v):
-        return jnp.sum(_flash(q, k, v).astype(jnp.float32))
-    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+def _flash_noncausal(q, k, v):
+    return flash_attention(q, k, v, causal=False, interpret=False)
+
+
+def _grad(fn):
+    def grad(q, k, v):
+        def loss(q, k, v):
+            return jnp.sum(fn(q, k, v).astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return grad
+
+
+_flash_grad = _grad(_flash)
 
 
 def _layernorm_grad(x, g, b):
@@ -84,6 +93,17 @@ CASES = {
                                    ((1, 8, 512, 128), bf16),
                                    ((1, 8, 512, 128), bf16)]),
     "flash_grad_12h_hd64": (_flash_grad, [((8, 12, 256, 64), bf16)] * 3),
+    # the cells' own shapes, at the tiles the kernel chooses (512)
+    "flash_fwd_prefill_s2048": (_flash, [((4, 24, 2048, 128), bf16),
+                                         ((4, 8, 2048, 128), bf16),
+                                         ((4, 8, 2048, 128), bf16)]),
+    "flash_fwd_whisper_causal": (_flash, [((16, 12, 512, 64), bf16)] * 3),
+    "flash_fwd_whisper_noncausal": (_flash_noncausal,
+                                    [((16, 12, 512, 64), bf16)] * 3),
+    "flash_grad_whisper_causal": (_flash_grad,
+                                  [((16, 12, 512, 64), bf16)] * 3),
+    "flash_grad_whisper_noncausal": (_grad(_flash_noncausal),
+                                     [((16, 12, 512, 64), bf16)] * 3),
     "layernorm_grad_d768": (_layernorm_grad,
                             [((8, 256, 768), f32), ((768,), f32),
                              ((768,), f32)]),
