@@ -10,7 +10,8 @@ whisper-small at full width (``launch.train``), with every tile op,
 flash attention and its backward running as compiled Pallas. Weights are
 random, from a fixed seed. ``--four-chips`` checks mistral-nemo-12b
 sharded over ``model=4`` against one device (depth cut to 4 layers),
-then serves the full-depth model sharded.
+then serves the full-depth model through ``launch.serve.Server`` on that
+mesh.
 
 Each phase prints one line; any failure exits non-zero, and no TPU means
 exit 1 before anything runs. The last line of standard output is the
@@ -163,11 +164,12 @@ def phase_four_chips():
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_config
     from repro.launch import steps as S
-    from repro.launch.mesh import make_debug_mesh
+    from repro.launch.mesh import make_local_mesh
+    from repro.launch.serve import Request, Server
     from repro.models import get_model
     from repro.parallel import ctx, param_specs, to_named
 
-    mesh = make_debug_mesh(data=1, model=4)
+    mesh = make_local_mesh(data=1, model=4)
     key = jax.random.PRNGKey(0)
     rng = np.random.default_rng(0)
 
@@ -213,25 +215,22 @@ def phase_four_chips():
           f"tokens_one_device={gen_1.tolist()} "
           f"wall_incl_setup={time.perf_counter() - t0:.1f}s", flush=True)
 
-    # 2) full depth, sharded: answer 2 requests
+    # 2) full depth, sharded, through the normal serving path
     t0 = time.perf_counter()
-    cfg = get_config("mistral-nemo-12b")
-    model, params = sharded(cfg)
-    n_params = sum(x.size for x in jax.tree.leaves(params))
-    toks = rng.integers(1, cfg.vocab, size=(2, 64)).astype(np.int32)
-    pre, last, gen = serve(model, cfg, params,
-                           jax.device_put(toks, NamedSharding(mesh, P())),
-                           8, True)
-    if not (bool(jnp.all(jnp.isfinite(pre))) and
-            bool(jnp.all(jnp.isfinite(last)))):
-        fail("four-chips: non-finite logits at full depth")
-    if not ((0 <= gen) & (gen < cfg.vocab)).all():
-        fail(f"four-chips: bad tokens {gen}")
+    srv = Server("mistral-nemo-12b", smoke=False, max_batch=2, mesh=mesh)
+    cfg = srv.cfg
+    n_params = sum(x.size for x in jax.tree.leaves(srv.params))
+    reqs = [Request(rid=i, max_new=8, prompt=rng.integers(
+        1, cfg.vocab, size=64).astype(np.int32)) for i in range(2)]
+    out = srv.generate(reqs)
+    gen = np.asarray([out[r.rid] for r in reqs])
+    if gen.shape != (2, 8) or not ((0 <= gen) & (gen < cfg.vocab)).all():
+        fail(f"four-chips: bad tokens {out}")
     check_guard("four-chips")
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in jax.devices()[:4])
     print(f"four-chips serve: mistral-nemo-12b layers={cfg.n_layers} "
-          f"params={n_params} requests=2 tokens={gen.size} "
+          f"params={n_params} mesh={srv.metrics['mesh']} requests=2 "
           f"tokens={gen.tolist()} peak_bytes_per_chip={peak} "
           f"wall_incl_setup={time.perf_counter() - t0:.1f}s", flush=True)
 

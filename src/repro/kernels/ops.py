@@ -130,8 +130,10 @@ def _local(fn: Callable, arrays, specs, out_spec):
 
 def _pallas_tile(name: str, arrays, scalars):
     """The Pallas tile op; under a mesh every device runs it over
-    replicated operands. Scalars travel as arguments so traced values
-    (the learning rate) cross into ``shard_map``."""
+    replicated operands, gathered where they came sharded: those
+    gathers carry the ``tile_gather`` scope in their ``op_name``.
+    Scalars travel as arguments so traced values (the learning rate)
+    cross into ``shard_map``."""
     names = sorted(scalars)
     n = len(arrays)
 
@@ -140,7 +142,13 @@ def _pallas_tile(name: str, arrays, scalars):
 
     args = list(arrays) + [jnp.asarray(scalars[k], jnp.float32)
                            for k in names]
-    return _local(run, args, (P(),) * len(args), P())
+    if ctx.active_mesh() is None:
+        return run(*args)
+    with jax.named_scope("tile_gather"):
+        # the partitioner names a gather after the op whose value it
+        # gathers: a barrier here puts the operands' gathers in the scope
+        args = jax.lax.optimization_barrier(args)
+        return _local(run, args, (P(),) * len(args), P())
 
 
 def _tile(name: str, *arrays, **scalars):

@@ -37,9 +37,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _mesh(shape, axes, devices[:need])
 
 
-def make_debug_mesh(data: int = 1, model: int = 1):
-    """Tiny mesh over available devices for tests."""
+def make_local_mesh(data: int = 1, model: int = 1):
+    """A (data, model) mesh over the first ``data * model`` devices this
+    process sees: one host's chips, or the CPU's virtual devices."""
     need = data * model
     devices = jax.devices()
-    assert len(devices) >= need
+    if len(devices) < need:
+        raise RuntimeError(f"mesh ({data}, {model}) needs {need} devices, "
+                           f"have {len(devices)}")
     return _mesh((data, model), ("data", "model"), devices[:need])

@@ -13,6 +13,9 @@ CPU-scale entry:
   PYTHONPATH=src python -m repro.launch.serve --arch mamba2-1.3b --smoke
 Full published width (on a chip):
   PYTHONPATH=src python -m repro.launch.serve --arch minitron-4b --full
+Tensor-parallel over the four chips of one host:
+  PYTHONPATH=src python -m repro.launch.serve --arch mistral-nemo-12b \
+      --full --model-parallel 4
 """
 from __future__ import annotations
 
@@ -26,13 +29,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core import SaturatorConfig
 from repro.core.telemetry import telemetry
 from repro.kernels import ops
 from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_local_mesh
 from repro.models import get_model
+from repro.parallel import cache_specs, ctx, param_specs, to_named
 
 from repro.cache import default_cache_dir
 
@@ -54,10 +60,17 @@ class Request:
 
 
 class Server:
+    """Greedy batched server. With a mesh (given, or the one active in
+    ``parallel.ctx`` when the server is built) the weights are made
+    already sharded by ``param_specs``, prefill and decode trace and run
+    under that mesh, and the KV cache is laid out by ``cache_specs``.
+    Without one, everything stays on the default device."""
+
     def __init__(self, arch: str, *, smoke: bool = True, max_batch: int = 4,
                  max_seq: int = 128, seed: int = 0,
                  cache_dir: Optional[str] = None,
-                 verify: Optional[str] = None):
+                 verify: Optional[str] = None,
+                 mesh: Optional[Mesh] = None):
         # every saturated tile op the model layers dispatch through
         # repro.kernels.ops is built (or replayed) via this cache
         if cache_dir is not None:
@@ -67,18 +80,46 @@ class Server:
         arch = ARCH_IDS.get(arch, arch)
         self.cfg = get_smoke_config(arch) if smoke else get_config(arch)
         self.model = get_model(self.cfg)
-        # jitted: the random init fuses into bf16 outputs (no f32 copy of
-        # the largest weight alive at full width)
-        self.params = jax.jit(self.model.init)(jax.random.PRNGKey(seed))
+        self.mesh = mesh if mesh is not None else ctx.active_mesh()
+        key = jax.random.PRNGKey(seed)
+        if self.mesh is None:
+            # jitted: the random init fuses into bf16 outputs (no f32
+            # copy of the largest weight alive at full width)
+            self.params = jax.jit(self.model.init)(key)
+            self._decode = jax.jit(self.model.decode_step)
+        else:
+            # made in place, each chip its own shard: the whole model
+            # need not fit one chip
+            shapes = jax.eval_shape(self.model.init, key)
+            psh = to_named(param_specs(self.cfg, shapes, self.mesh),
+                           self.mesh)
+            self.params = jax.jit(self.model.init, out_shardings=psh)(key)
+            decode = jax.jit(self._decode_sharded)
+
+            def _decode(params, cache, tok):
+                with ctx.activate(self.mesh):
+                    return decode(params, cache, tok)
+            self._decode = _decode
         self.max_batch = max_batch
         self.max_seq = max_seq
-        self._decode = jax.jit(self.model.decode_step)
         # metrics are mutated from every serving thread — concurrent
         # generate() calls are supported, so counter updates take this
         # lock (prevents lost increments / torn read-modify-write)
         self._metrics_lock = threading.Lock()
         self.metrics = {"prefills": 0, "decode_ticks": 0, "tokens": 0,
-                        "host_syncs": 0}
+                        "host_syncs": 0,
+                        "mesh": (dict(self.mesh.shape) if self.mesh
+                                 is not None else None)}
+
+    def _pin_cache(self, cache):
+        """The cache as ``cache_specs`` lays it out on the mesh."""
+        return jax.lax.with_sharding_constraint(
+            cache, to_named(cache_specs(self.cfg, cache, self.mesh),
+                            self.mesh))
+
+    def _decode_sharded(self, params, cache, tok):
+        logits, cache = self.model.decode_step(params, cache, tok)
+        return logits, self._pin_cache(cache)
 
     def _bump(self, key: str, n: int = 1):
         with self._metrics_lock:
@@ -96,12 +137,18 @@ class Server:
 
     def _prefill_batch(self, prompts: np.ndarray):
         tokens = jnp.asarray(prompts, jnp.int32)
-        if self.cfg.family == "encdec":
-            frames = jnp.zeros((tokens.shape[0], tokens.shape[1],
-                                self.cfg.d_model), jnp.float32)
-            logits, cache = self.model.prefill(self.params, tokens, frames)
-        else:
-            logits, cache = self.model.prefill(self.params, tokens)
+        if self.mesh is not None:
+            tokens = jax.device_put(tokens, NamedSharding(self.mesh, P()))
+        with ctx.activate(self.mesh):
+            if self.cfg.family == "encdec":
+                frames = jnp.zeros((tokens.shape[0], tokens.shape[1],
+                                    self.cfg.d_model), jnp.float32)
+                logits, cache = self.model.prefill(self.params, tokens,
+                                                   frames)
+            else:
+                logits, cache = self.model.prefill(self.params, tokens)
+            if self.mesh is not None:
+                cache = self._pin_cache(cache)
         self._bump("prefills")
         return logits, cache
 
@@ -157,6 +204,9 @@ def main(argv=None):
                     help="persistent saturation cache directory")
     ap.add_argument("--no-cache", action="store_true",
                     help="disable the on-disk saturation cache")
+    ap.add_argument("--model-parallel", type=int, default=1,
+                    help="tensor-parallel over this many devices "
+                         "(a data=1, model=N mesh); 1 serves on one")
     ap.add_argument("--verify", default=None,
                     choices=["off", "cheap", "full"],
                     help="static verification level for every kernel "
@@ -167,8 +217,11 @@ def main(argv=None):
     # explicit arg > CLI flag > env var (REPRO_SAT_CACHE / REPRO_VERIFY)
     sat = SaturatorConfig.from_env(flags=args)
     enable_compile_cache()
+    mesh = (make_local_mesh(1, args.model_parallel)
+            if args.model_parallel > 1 else None)
     srv = Server(args.arch, smoke=args.smoke,
-                 cache_dir=sat.cache_dir or None, verify=sat.verify)
+                 cache_dir=sat.cache_dir or None, verify=sat.verify,
+                 mesh=mesh)
     rng = np.random.default_rng(0)
     reqs = [Request(rid=i,
                     prompt=rng.integers(1, srv.cfg.vocab,
@@ -184,7 +237,8 @@ def main(argv=None):
           f"{srv.metrics['tokens']} tokens in {dt:.1f}s "
           f"({srv.metrics['prefills']} prefills, "
           f"{srv.metrics['decode_ticks']} ticks, "
-          f"{srv.metrics['host_syncs']} host syncs)")
+          f"{srv.metrics['host_syncs']} host syncs, "
+          f"mesh {srv.metrics['mesh']})")
     print(f"  saturation cache: hits={sat.get('cache_hits', 0)} "
           f"warm={sat.get('cache_warm_starts', 0)} "
           f"misses={sat.get('cache_misses', 0)} "
