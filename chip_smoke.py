@@ -121,7 +121,7 @@ def phase_serve():
         fail(f"serve: prefill logits rel err {err:.3e} vs ref "
              f"> {LOGITS_REL_TOL}")
     tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
-    hlo = srv._decode.lower(srv.params, cache, tok).as_text()
+    hlo = srv._decode_jit.lower(srv.params, cache, tok).as_text()
     kernels = sorted({k for k in ("tile_rmsnorm", "tile_swiglu",
                                   "tile_rotary") if k in hlo})
     if "tpu_custom_call" not in hlo or len(kernels) < 3:

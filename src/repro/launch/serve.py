@@ -86,7 +86,7 @@ class Server:
             # jitted: the random init fuses into bf16 outputs (no f32
             # copy of the largest weight alive at full width)
             self.params = jax.jit(self.model.init)(key)
-            self._decode = jax.jit(self.model.decode_step)
+            step = self.model.decode_step
         else:
             # made in place, each chip its own shard: the whole model
             # need not fit one chip
@@ -94,12 +94,10 @@ class Server:
             psh = to_named(param_specs(self.cfg, shapes, self.mesh),
                            self.mesh)
             self.params = jax.jit(self.model.init, out_shardings=psh)(key)
-            decode = jax.jit(self._decode_sharded)
-
-            def _decode(params, cache, tok):
-                with ctx.activate(self.mesh):
-                    return decode(params, cache, tok)
-            self._decode = _decode
+            step = self._decode_sharded
+        # the cache is donated: each tick writes its new K/V rows into
+        # the buffers it is given, and the caller's cache is consumed
+        self._decode_jit = jax.jit(step, donate_argnums=(1,))
         self.max_batch = max_batch
         self.max_seq = max_seq
         # metrics are mutated from every serving thread — concurrent
@@ -107,7 +105,7 @@ class Server:
         # lock (prevents lost increments / torn read-modify-write)
         self._metrics_lock = threading.Lock()
         self.metrics = {"prefills": 0, "decode_ticks": 0, "tokens": 0,
-                        "host_syncs": 0,
+                        "host_syncs": 0, "decode_cache": {},
                         "mesh": (dict(self.mesh.shape) if self.mesh
                                  is not None else None)}
 
@@ -118,8 +116,36 @@ class Server:
                             self.mesh))
 
     def _decode_sharded(self, params, cache, tok):
-        logits, cache = self.model.decode_step(params, cache, tok)
+        logits, cache = self.model.decode_step(params, self._pin_cache(cache),
+                                               tok)
         return logits, self._pin_cache(cache)
+
+    def _decode(self, params, cache, tok):
+        """One decode tick; consumes ``cache``. The first tick of each
+        cache shape also records what the compiled step keeps in place
+        (``_record_decode_cache``; two threads meeting a new shape at
+        once both record it, with equal entries)."""
+        with ctx.activate(self.mesh):
+            if _cache_key(cache) not in self.metrics["decode_cache"]:
+                self._record_decode_cache(params, cache, tok)
+            return self._decode_jit(params, cache, tok)
+
+    def _record_decode_cache(self, params, cache, tok):
+        """``metrics["decode_cache"][shape]``: the cache's bytes on one
+        device, as laid out there, and of the compiled step's, the bytes
+        its outputs share with its donated inputs (``aliased_bytes``, all
+        of the cache when it is updated in place) and its temporaries
+        (``temp_bytes``, near the cache's size where a whole copy of it is
+        written). The jit's call that follows reuses this compile."""
+        mem = self._decode_jit.lower(params, cache, tok).compile() \
+            .memory_analysis()
+        entry = {"cache_bytes": sum(
+                     a.addressable_shards[0].data.on_device_size_in_bytes()
+                     for a in jax.tree.leaves(cache)),
+                 "aliased_bytes": int(mem.alias_size_in_bytes),
+                 "temp_bytes": int(mem.temp_size_in_bytes)}
+        with self._metrics_lock:
+            self.metrics["decode_cache"][_cache_key(cache)] = entry
 
     def _bump(self, key: str, n: int = 1):
         with self._metrics_lock:
@@ -191,6 +217,13 @@ class Server:
         return results
 
 
+def _cache_key(cache) -> str:
+    """A decode cache's shape: batch, then positions where it keeps K/V."""
+    if "k" in cache:
+        return "x".join(map(str, cache["k"].shape[1:4:2]))
+    return str(jax.tree.leaves(cache["ssm"])[0].shape[1])
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minitron-4b")
@@ -239,6 +272,9 @@ def main(argv=None):
           f"{srv.metrics['decode_ticks']} ticks, "
           f"{srv.metrics['host_syncs']} host syncs, "
           f"mesh {srv.metrics['mesh']})")
+    for shape, c in srv.metrics["decode_cache"].items():
+        print(f"  decode cache {shape}: {c['cache_bytes']} bytes, "
+              f"aliased {c['aliased_bytes']}, temp {c['temp_bytes']}")
     print(f"  saturation cache: hits={sat.get('cache_hits', 0)} "
           f"warm={sat.get('cache_warm_starts', 0)} "
           f"misses={sat.get('cache_misses', 0)} "

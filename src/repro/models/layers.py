@@ -226,11 +226,38 @@ def attn_prefill(p, x, cos, sin, cfg: ModelConfig):
     return _merge_heads(o) @ wo, kv_cache
 
 
-@jax.named_scope("attn")
 def attn_decode(p, x1, kv_cache, pos, cfg: ModelConfig,
                 cos1=None, sin1=None):
     """One-token decode. x1:(B,1,D); kv_cache: (k,v) each (B,KH,S,hd);
     pos: () current position. Cache updated in place at pos."""
+    out, kv_cache, _ = _attn_decode(p, x1, kv_cache, pos, cfg, cos1, sin1)
+    return out, kv_cache
+
+
+def attn_decode_stacked(p, x1, kv_cache, layer, pos, cfg: ModelConfig,
+                        cos1=None, sin1=None):
+    """``attn_decode`` for entry ``layer`` of a stacked cache: kv_cache
+    (K, V) each (L, B, KH, S, hd). The attention is ``attn_decode``'s over
+    the entry's slices; of the stacked cache only the new row is written,
+    in place. The slices are read from the cache as given, not after the
+    row write: reading after it let XLA pick another layout for the
+    layer scan's carry, with whole-cache copies into and out of the loop
+    (v5e compile). Read and write lie outside the ``attn`` scope, as the
+    scan's own slicing and stacking of the cache did."""
+    K, V = kv_cache
+    out, _, (k1, v1) = _attn_decode(
+        p, x1, (lax.dynamic_index_in_dim(K, layer, keepdims=False),
+                lax.dynamic_index_in_dim(V, layer, keepdims=False)),
+        pos, cfg, cos1, sin1)
+    K = lax.dynamic_update_slice(K, k1[None], (layer, 0, 0, pos, 0))
+    V = lax.dynamic_update_slice(V, v1[None], (layer, 0, 0, pos, 0))
+    return out, (K, V)
+
+
+@jax.named_scope("attn")
+def _attn_decode(p, x1, kv_cache, pos, cfg: ModelConfig, cos1, sin1):
+    """``attn_decode``, also returning the new K/V rows (B,KH,1,hd) in the
+    cache's dtype."""
     k_c, v_c = kv_cache
     q = _split_heads(x1 @ p["wq"], cfg.n_heads, cfg.head_dim)
     k1 = _split_heads(x1 @ p["wk"], cfg.n_kv_heads, cfg.head_dim)
@@ -238,10 +265,9 @@ def attn_decode(p, x1, kv_cache, pos, cfg: ModelConfig,
     if cos1 is not None:
         q = ops.rotary(q, cos1[:, None], sin1[:, None]).astype(x1.dtype)
         k1 = ops.rotary(k1, cos1[:, None], sin1[:, None]).astype(x1.dtype)
-    k_c = lax.dynamic_update_slice(k_c, k1.astype(k_c.dtype),
-                                   (0, 0, pos, 0))
-    v_c = lax.dynamic_update_slice(v_c, v1.astype(v_c.dtype),
-                                   (0, 0, pos, 0))
+    k1, v1 = k1.astype(k_c.dtype), v1.astype(v_c.dtype)
+    k_c = lax.dynamic_update_slice(k_c, k1, (0, 0, pos, 0))
+    v_c = lax.dynamic_update_slice(v_c, v1, (0, 0, pos, 0))
     S = k_c.shape[2]
     # mask out positions beyond pos
     valid = jnp.arange(S) <= pos
@@ -272,7 +298,7 @@ def attn_decode(p, x1, kv_cache, pos, cfg: ModelConfig,
     o = (acc / l).astype(x1.dtype)
     o = o.reshape(B, cfg.n_heads, 1, cfg.head_dim)
     o = ctx.constrain(o, "dp", None, None, None)
-    return _merge_heads(o) @ p["wo"], (k_c, v_c)
+    return _merge_heads(o) @ p["wo"], (k_c, v_c), (k1, v1)
 
 
 # ---------------------------------------------------------------------------

@@ -308,12 +308,18 @@ class LM:
         h = params["embed"][token]
         pos = cache["pos"]
         cos1, sin1 = self._cos_sin(pos[None].astype(jnp.int32))
+        # The stacked K/V cache rides in the scan's carry, and each layer
+        # writes only its new row into it (attn_decode_stacked); as the
+        # scan's xs/ys the whole cache would be written out again every
+        # tick. With the cache donated (launch/serve.py) the row write
+        # lands in the given buffers.
         if cfg.family in ("dense", "moe", "vlm"):
-            def body(h, xs):
-                lp, kc, vc = xs
+            def body(carry, xs):
+                h, kv = carry
+                lp, l = xs
                 xn = L.norm_apply(lp["ln1"], h, cfg)
-                a, (kc, vc) = L.attn_decode(lp["attn"], xn, (kc, vc), pos,
-                                            cfg, cos1, sin1)
+                a, kv = L.attn_decode_stacked(lp["attn"], xn, kv, l, pos,
+                                              cfg, cos1, sin1)
                 h = h + a
                 if cfg.family == "moe":
                     y, _ = L.moe_apply(lp["moe"],
@@ -321,9 +327,10 @@ class LM:
                 else:
                     y = L.mlp_apply(lp["mlp"],
                                     L.norm_apply(lp["ln2"], h, cfg), cfg)
-                return h + y, (kc, vc)
-            h, (ks, vs) = lax.scan(body, h, (params["layers"], cache["k"],
-                                             cache["v"]))
+                return (h + y, kv), None
+            (h, (ks, vs)), _ = lax.scan(
+                body, (h, (cache["k"], cache["v"])),
+                (params["layers"], jnp.arange(cfg.n_layers)))
             cache = dict(cache, k=ks, v=vs, pos=pos + 1)
         elif cfg.family == "ssm":
             def body(h, xs):
@@ -343,8 +350,9 @@ class LM:
                 lambda a: a.reshape((n_out, k) + a.shape[1:]), cache["ssm"])
             shared = params["shared"]
 
-            def outer(h, xs):
-                gp, st, kc, vc = xs
+            def outer(carry, xs):
+                h, kv = carry
+                gp, st, g = xs
 
                 def inner(hh, ys):
                     lp, s1 = ys
@@ -353,14 +361,15 @@ class LM:
                     return hh + y, s1
                 h, st = lax.scan(inner, h, (gp, st))
                 xn = L.norm_apply(shared["ln1"], h, cfg)
-                a, (kc, vc) = L.attn_decode(shared["attn"], xn, (kc, vc),
-                                            pos, cfg, cos1, sin1)
+                a, kv = L.attn_decode_stacked(shared["attn"], xn, kv, g,
+                                              pos, cfg, cos1, sin1)
                 h = h + a
                 h = h + L.mlp_apply(shared["mlp"],
                                     L.norm_apply(shared["ln2"], h, cfg), cfg)
-                return h, (st, kc, vc)
-            h, (gstates, ks, vs) = lax.scan(
-                outer, h, (grouped, gstates, cache["k"], cache["v"]))
+                return (h, kv), st
+            (h, (ks, vs)), gstates = lax.scan(
+                outer, (h, (cache["k"], cache["v"])),
+                (grouped, gstates, jnp.arange(n_out)))
             cache = dict(cache,
                          ssm=jax.tree.map(
                              lambda a: a.reshape((-1,) + a.shape[2:]),

@@ -1,6 +1,8 @@
 """Per-architecture smoke tests (reduced configs): one forward/train step
 on CPU asserting output shapes + no NaNs, plus prefill↔decode consistency
 against the full-sequence logits."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -94,6 +96,89 @@ def test_decode_matches_teacher_forcing(arch):
         np.testing.assert_allclose(np.asarray(logits)[:, 0], full[:, t],
                                    atol=3e-2, rtol=3e-2,
                                    err_msg=f"step {t}")
+
+
+def _restacking_decode_step(model, params, cache, token):
+    """The decode step with the stacked K/V cache as the layer scan's xs
+    and ys, so every tick writes the whole cache out again: the reference
+    for ``LM.decode_step``, which writes only the new row in place."""
+    from jax import lax
+    from repro.models import layers as L
+    cfg = model.cfg
+    h = params["embed"][token]
+    pos = cache["pos"]
+    cos1, sin1 = model._cos_sin(pos[None].astype(jnp.int32))
+    if cfg.family == "dense":
+        def body(h, xs):
+            lp, kc, vc = xs
+            xn = L.norm_apply(lp["ln1"], h, cfg)
+            a, (kc, vc) = L.attn_decode(lp["attn"], xn, (kc, vc), pos,
+                                        cfg, cos1, sin1)
+            h = h + a
+            y = L.mlp_apply(lp["mlp"], L.norm_apply(lp["ln2"], h, cfg), cfg)
+            return h + y, (kc, vc)
+        h, (ks, vs) = lax.scan(body, h, (params["layers"], cache["k"],
+                                         cache["v"]))
+        cache = dict(cache, k=ks, v=vs, pos=pos + 1)
+    else:  # hybrid
+        k = cfg.shared_attn_every
+        n_out = cfg.n_layers // k
+        group = lambda a: a.reshape((n_out, k) + a.shape[1:])
+        shared = params["shared"]
+
+        def outer(h, xs):
+            gp, st, kc, vc = xs
+
+            def inner(hh, ys):
+                lp, s1 = ys
+                xn = L.norm_apply(lp["ln1"], hh, cfg)
+                y, s1 = L.mamba_decode(lp["mamba"], xn, s1, cfg)
+                return hh + y, s1
+            h, st = lax.scan(inner, h, (gp, st))
+            xn = L.norm_apply(shared["ln1"], h, cfg)
+            a, (kc, vc) = L.attn_decode(shared["attn"], xn, (kc, vc), pos,
+                                        cfg, cos1, sin1)
+            h = h + a
+            h = h + L.mlp_apply(shared["mlp"],
+                                L.norm_apply(shared["ln2"], h, cfg), cfg)
+            return h, (st, kc, vc)
+        h, (gstates, ks, vs) = lax.scan(
+            outer, h, (jax.tree.map(group, params["layers"]),
+                       jax.tree.map(group, cache["ssm"]), cache["k"],
+                       cache["v"]))
+        cache = dict(cache, k=ks, v=vs, pos=pos + 1,
+                     ssm=jax.tree.map(
+                         lambda a: a.reshape((-1,) + a.shape[2:]), gstates))
+    h = L.norm_apply(params["final_norm"], h, cfg)
+    logits = (h.astype(jnp.float32)
+              @ model._unembed(params).astype(jnp.float32))
+    return logits, cache
+
+
+@pytest.mark.parametrize("arch", ["minitron_4b", "zamba2_2p7b"])
+def test_decode_writes_cache_in_place_bitwise(arch):
+    """Writing only the new K/V row into the carried cache gives the same
+    logits and caches, bit for bit, as re-stacking the whole cache."""
+    cfg = get_smoke_config(arch)
+    model = get_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 12), 0,
+                                cfg.vocab)
+    _, cache = model.prefill(params, tokens[:, :6], max_seq=16)
+    ref_cache = cache
+    step = jax.jit(model.decode_step)
+    ref_step = jax.jit(functools.partial(_restacking_decode_step, model))
+    for t in range(6, 12):
+        logits, cache = step(params, cache, tokens[:, t:t + 1])
+        ref_logits, ref_cache = ref_step(params, ref_cache,
+                                         tokens[:, t:t + 1])
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(ref_logits))
+        assert jax.tree.structure(cache) == jax.tree.structure(ref_cache)
+        for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(ref_cache)):
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+    assert int(cache["pos"]) == 12
 
 
 def test_cells_enumeration():

@@ -23,9 +23,11 @@ SEED = 2 ** 31 + 101
 _CHILD = r"""
 import json, os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import re
 import jax, jax.numpy as jnp, numpy as np
 from repro.launch.mesh import make_local_mesh
 from repro.launch.serve import Request, Server
+from repro.parallel import ctx
 
 seed = int(sys.argv[1])
 prompts = np.random.default_rng(seed).integers(0, 512, (2, 24)).astype(np.int32)
@@ -47,6 +49,20 @@ for shape in (None, (1, 2), (1, 4)):
         if len(toks) < 3:
             toks.append(jnp.argmax(logits[:, -1], -1)[:, None]
                         .astype(jnp.int32))
+    collectives = []
+    if mesh is not None:
+        # the compiled step's collectives (the jit's own executable)
+        with ctx.activate(mesh):
+            hlo = srv._decode_jit.lower(srv.params, cache,
+                                        toks[0]).compile().as_text()
+        for line in hlo.splitlines():
+            op = re.search(r"= (.*?) (all-gather|all-reduce|all-to-all|"
+                           r"collective-permute|reduce-scatter)(-start)?\(",
+                           line)
+            if op:
+                collectives.append(max(
+                    int(np.prod([int(d) for d in dims.split(",") if d]))
+                    for dims in re.findall(r"\[([\d,]*)\]", op.group(1))))
     reqs = [Request(rid=i, prompt=prompts[i], max_new=3) for i in range(2)]
     served = srv.generate(reqs)
     out[str(shape)] = {
@@ -57,14 +73,14 @@ for shape in (None, (1, 2), (1, 4)):
         "cache_shard": list(cache["k"].sharding.shard_shape(cache["k"].shape)),
         "wg_shard": list(srv.params["layers"]["mlp"]["wg"].sharding
                          .shard_shape(srv.params["layers"]["mlp"]["wg"].shape)),
-        "mesh": srv.metrics["mesh"]}
+        "mesh": srv.metrics["mesh"],
+        "decode_cache": srv.metrics["decode_cache"],
+        "collectives": collectives}
 
 # the Pallas tile ops (interpret mode here) under the (1, 2) mesh: each
 # runs through shard_map over gathered operands, and those gathers carry
 # the tile_gather scope
-import re
 from repro.kernels import ops
-from repro.parallel import ctx
 ops.set_impl("pallas")
 srv = Server("mistral-nemo-12b", smoke=True, max_batch=2, seed=seed,
              mesh=make_local_mesh(1, 2))
@@ -157,6 +173,23 @@ def test_cache_split_by_head_or_sequence(served):
     # and the sequence axis does instead
     assert served["(1, 2)"]["cache_shard"] == [L, B, KH // 2, S, hd]
     assert served["(1, 4)"]["cache_shard"] == [L, B, KH, S // 4, hd]
+
+
+@pytest.mark.parametrize("shape", ["None", "(1, 2)", "(1, 4)"])
+def test_decode_keeps_its_cache_in_place(served, shape):
+    """Split by head or by sequence, the decode step writes its row into
+    the donated cache: the compiled step shares all of the cache's bytes
+    on a device with its inputs, and no collective moves as much as one
+    layer's share of the cache."""
+    (key, entry), = served[shape]["decode_cache"].items()
+    L, B, KH, S, hd = served[shape]["cache_shard"]
+    assert key == "%dx%d" % (B, served[shape]["cache_shape"][3])
+    # K and V in bf16 and the int32 position, per device
+    assert entry["cache_bytes"] == 2 * 2 * L * B * KH * S * hd + 4
+    assert entry["aliased_bytes"] == entry["cache_bytes"]
+    if shape != "None":
+        assert served[shape]["collectives"]
+        assert max(served[shape]["collectives"]) < B * KH * S * hd
 
 
 @pytest.mark.parametrize("shape", ["None", "(1, 2)", "(1, 4)",

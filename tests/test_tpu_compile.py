@@ -9,6 +9,7 @@ Widths: minitron-4b's serve path (d_model 3072, d_ff 9216, head_dim
 of 64). The topology is described in a fixture, never at import, so
 every test worker collects the same tests."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -116,3 +117,36 @@ def test_compiles_for_v5e(case, one_chip):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_writes_one_cache_row_for_v5e(one_chip):
+    """minitron-4b's decode step at the prefill cell's cache (batch 4,
+    2304 positions), compiled for v5e with the cache donated: every
+    update of the stacked (L, B, KH, S, hd) cache writes one position,
+    and the step needs no temporaries near the cache's size (a whole
+    copy of the cache, written every tick, showed up there)."""
+    from repro.configs import get_config
+    from repro.models import get_model
+    model = get_model(get_config("minitron-4b"))
+
+    def specs(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    params = specs(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = specs(jax.eval_shape(lambda: model.init_cache(4, 2304)))
+    tok = jax.ShapeDtypeStruct((4, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, cache, tok).compile()
+    hlo = compiled.as_text()
+    shapes = dict(re.findall(r"%(\S+) = (\w+\[[\d,]*\])", hlo))
+    stacked = "bf16[%s]" % ",".join(map(str, cache["k"].shape))
+    updates = [shapes[m.group(1)] for m in re.finditer(
+        r"= (?:\w+\[[\d,]*\])\S* dynamic-update-slice\(%\S+, %([^,\s]+),",
+        hlo) if m.group(0).startswith("= " + stacked)]
+    assert len(updates) == 2   # K and V
+    assert all(u.split(",")[3] == "1" for u in updates), updates
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 0.01 * cache_bytes

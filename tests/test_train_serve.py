@@ -86,3 +86,54 @@ def test_serve_counts_host_syncs():
     # one device-to-host read per token served
     assert srv.metrics["host_syncs"] == sum(map(len, out.values())) == 6
     assert srv.metrics["decode_ticks"] == 2 + 1
+
+
+def _first_decode(arch):
+    srv = Server(arch, smoke=True, max_batch=2)
+    prompts = np.random.default_rng(3).integers(
+        1, srv.cfg.vocab, (2, 8)).astype(np.int32)
+    logits, cache = srv._prefill_batch(prompts)
+    tok = jax.numpy.argmax(logits[:, -1], -1)[:, None].astype(np.int32)
+    return srv, cache, tok
+
+
+@pytest.mark.parametrize("arch", ["minitron-4b", "zamba2-2.7b"])
+def test_serve_decode_consumes_its_cache(arch):
+    """The decode step is given the cache to write in place: the buffers
+    it was given are gone, and the compiled step's outputs share all of
+    the cache's bytes with its inputs."""
+    srv, cache, tok = _first_decode(arch)
+    given = jax.tree.leaves(cache)
+    _, cache = srv._decode(srv.params, cache, tok)
+    assert all(a.is_deleted() for a in given)
+    assert not any(a.is_deleted() for a in jax.tree.leaves(cache))
+    (shape, entry), = srv.metrics["decode_cache"].items()
+    assert shape == "2x264"
+    assert entry["cache_bytes"] == sum(a.nbytes for a in given)
+    assert entry["aliased_bytes"] == entry["cache_bytes"]
+
+
+def test_serve_decode_compiles_once_per_shape():
+    """Recording the compiled step's memory does not compile it twice:
+    the jitted call reuses the executable, and a new batch size adds one
+    compile and one entry."""
+    srv, cache, tok = _first_decode("minitron-4b")
+    compiles = []
+
+    def on_time(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+    jax.monitoring.register_event_duration_secs_listener(on_time)
+    try:
+        for _ in range(3):
+            _, cache = srv._decode(srv.params, cache, tok)
+        assert len(compiles) == 1
+        logits, cache = srv._prefill_batch(np.ones((1, 8), np.int32))
+        tok = jax.numpy.argmax(logits[:, -1], -1)[:, None].astype(np.int32)
+        n = len(compiles)
+        for _ in range(2):
+            _, cache = srv._decode(srv.params, cache, tok)
+        assert len(compiles) == n + 1
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_time)
+    assert sorted(srv.metrics["decode_cache"]) == ["1x264", "2x264"]
