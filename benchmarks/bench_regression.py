@@ -32,13 +32,7 @@ or when the PR-5 **scheduling legs** break:
   show the cost-driven order within ``TOLERANCE_PCT`` of bulk per
   kernel (paired per-rep deltas — nothing is re-timed in CI), and
 * the schedule-aware profile must keep beating the PR-4 profile on
-  Spearman or MAPE over the cost-schedule measurements;
-
-or when the PR-8 **pipelined-emitter leg** breaks: over a kernel
-subset, the ``pallas_pipelined`` emitter's interpret fallback must stay
-byte-identical to the synchronous emitter (and its CPU outputs
-bit-identical), and its recorded async copy plans must verify clean
-(see ``benchmarks/pipelined_smoke.py``).
+  Spearman or MAPE over the cost-schedule measurements.
 
 The gate also (re)writes the top-level ``BENCH_5.json`` perf
 trajectory (per-kernel predicted + measured ns by schedule, profile
@@ -421,18 +415,6 @@ def check_bench9() -> list:
     return failures
 
 
-def check_pipelined() -> list:
-    """PR-8 pipelined-emitter leg (deterministic — no timing): over a
-    kernel subset, the ``pallas_pipelined`` emitter's interpret fallback
-    must stay byte-identical to the synchronous emitter, its outputs
-    bit-identical on CPU, and its async copy plan verify clean (every
-    start waited, waits dominate first use, semaphore parity, ≤2 in
-    flight). Reuses ``benchmarks/pipelined_smoke.py`` — CI's standalone
-    smoke job and this gate certify the same contract."""
-    from benchmarks.pipelined_smoke import SMOKE_KERNELS, run_pipelined_smoke
-    return run_pipelined_smoke(SMOKE_KERNELS)
-
-
 def check_calibration() -> list:
     """The predicted-vs-measured leg of the gate: every committed device
     profile must still rank kernels faithfully under the current model
@@ -510,8 +492,6 @@ def main() -> int:
     failures += check_schedule_measured()
     print("calibrated predicted-vs-measured check:")
     failures += check_calibration()
-    print("pipelined emitter leg (fallback identity + async plan):")
-    failures += check_pipelined()
     print("BENCH_6 serve-decode cache report:")
     failures += check_bench6()
     print("BENCH_9 statically-pruned block tuning:")

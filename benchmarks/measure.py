@@ -23,9 +23,7 @@ Three measurement lanes, each flagged with an explicit ``measured_kind``
   lower to the accelerator, ``"xla_interpret"`` on CPU where the
   interpret-mode kernel is traced and compiled by XLA (dispatch
   overhead gone, op costs remain; the honest label keeps the two from
-  being conflated). ``--backend pallas_pipelined`` swaps in the
-  pipelined emitter (interpret fallback on CPU — bit-identical source,
-  so CPU rows measure the same code with a compiled-lane label).
+  being conflated).
 * **NPB/SPEC suite kernels** (``benchmarks.kernel_suite`` — indexed
   loads/loops, not Pallas-tilable) run their saturated JAX thread body
   sequentially over the grid under one jit (``jax_<backend>_grid``);
@@ -84,8 +82,6 @@ SCHEDULES = ("source", "bulk", "cost")
 # profile when present, so the measured order is the calibrated
 # objective's pick, not the analytic guess
 SCHED_PROFILE = "cpu_pallas_interpret"
-# Pallas emission backends the tile lanes can measure (repro.core.emit)
-BACKENDS = ("pallas", "pallas_pipelined")
 
 
 def _backend() -> str:
@@ -121,14 +117,11 @@ def _sched_profile_name():
             if (PROFILE_DIR / f"{SCHED_PROFILE}.json").exists() else None)
 
 
-def _tile_op_for(name: str, schedule: str, emitter: str = None):
+def _tile_op_for(name: str, schedule: str):
     from repro.kernels.tile_programs import get_tile_op
-    # None (not "pallas") keeps pre-PR-8 cache fingerprints byte-identical
     return get_tile_op(name, schedule=schedule,
                        device_profile=(_sched_profile_name()
-                                       if schedule == "cost" else None),
-                       emitter=(emitter if emitter not in (None, "pallas")
-                                else None))
+                                       if schedule == "cost" else None))
 
 
 def _tile_features(op, schedule: str) -> dict:
@@ -145,7 +138,7 @@ def _tile_features(op, schedule: str) -> dict:
 
 
 def measure_tile_schedules(name: str, reps: int, warmup: int = 3,
-                           schedules=SCHEDULES, emitter: str = None) -> list:
+                           schedules=SCHEDULES) -> list:
     """Median per-call wall time of one tile program's Pallas kernel on
     a single (8, 128) tile (grid of one → per-call == per-instance),
     under every statement ``schedule``.
@@ -161,7 +154,7 @@ def measure_tile_schedules(name: str, reps: int, warmup: int = 3,
     cycles, outside the timed region.
     """
     import gc
-    ops = {s: _tile_op_for(name, s, emitter) for s in schedules}
+    ops = {s: _tile_op_for(name, s) for s in schedules}
     arrays, scalars = tile_inputs_for(next(iter(ops.values())).sk.ssa.prog)
     args = [jax.numpy.asarray(a) for a in arrays]
 
@@ -217,7 +210,7 @@ def measure_tile_kernel(name: str, reps: int, warmup: int = 3,
 
 
 def measure_tile_compiled(name: str, reps: int, warmup: int = 3,
-                          schedules=SCHEDULES, emitter: str = None) -> list:
+                          schedules=SCHEDULES) -> list:
     """The compiled lane (PR 8): the same tile kernels, each schedule
     jitted once and timed hot — ``measured_kind: "pallas_compiled"``.
 
@@ -230,7 +223,7 @@ def measure_tile_compiled(name: str, reps: int, warmup: int = 3,
     accelerator numbers. Interleaving/rotation/gc discipline matches
     :func:`measure_tile_schedules`."""
     import gc
-    ops = {s: _tile_op_for(name, s, emitter) for s in schedules}
+    ops = {s: _tile_op_for(name, s) for s in schedules}
     arrays, scalars = tile_inputs_for(next(iter(ops.values())).sk.ssa.prog)
     args = [jax.numpy.asarray(a) for a in arrays]
     native = _backend() != "cpu"
@@ -310,30 +303,24 @@ def measure_suite_kernel(name: str, reps: int, n: int = 64 * 64,
 # Harness
 # ---------------------------------------------------------------------------
 def measure_all(kernels=None, reps: int = 5, n: int = 64 * 64,
-                schedules=SCHEDULES, backend: str = "pallas",
-                compiled: bool = True) -> dict:
+                schedules=SCHEDULES, compiled: bool = True) -> dict:
     """Measure every requested kernel; returns the measurements document
     (also the ``measure`` section of ``benchmarks/run.py``). Tile
     kernels are timed once per statement schedule — same extracted
     term, different emission order — and, with ``compiled`` on a CPU
     host, once more per schedule under jit (the compiled lane; on
     accelerators the eager lane already *is* ``pallas_compiled``, so no
-    second lane runs). ``backend`` picks the Pallas emitter."""
+    second lane runs)."""
     from benchmarks.kernel_suite import SUITE
     from repro.analysis import DEFAULT_PARAMS, predict_ns, KernelFeatures
-    if backend not in BACKENDS:
-        raise ValueError(f"backend must be one of {BACKENDS}, "
-                         f"got {backend!r}")
     rows = []
     for name in TILE_KERNELS:
         if kernels and name not in kernels:
             continue
-        rows.extend(measure_tile_schedules(name, reps, schedules=schedules,
-                                           emitter=backend))
+        rows.extend(measure_tile_schedules(name, reps, schedules=schedules))
         if compiled and _backend() == "cpu":
             rows.extend(measure_tile_compiled(name, reps,
-                                              schedules=schedules,
-                                              emitter=backend))
+                                              schedules=schedules))
     for name in SUITE:
         if kernels and name not in kernels:
             continue
@@ -342,7 +329,7 @@ def measure_all(kernels=None, reps: int = 5, n: int = 64 * 64,
         feat = KernelFeatures.from_dict(r["features"])
         r["predicted_ns"] = predict_ns(feat, DEFAULT_PARAMS)
     return {"schema_version": MEASUREMENTS_SCHEMA_VERSION,
-            "backend": _backend(), "emitter": backend, "rows": rows}
+            "backend": _backend(), "emitter": "pallas", "rows": rows}
 
 
 def fit_profiles(doc: dict, out_dir: pathlib.Path = PROFILE_DIR) -> list:
@@ -468,11 +455,6 @@ def main(argv=None) -> int:
     ap.add_argument("--schedules", default=",".join(SCHEDULES),
                     help="comma-separated statement schedules to time "
                          f"per tile kernel (default {','.join(SCHEDULES)})")
-    ap.add_argument("--backend", choices=BACKENDS, default="pallas",
-                    help="Pallas emission backend for the tile lanes "
-                         "(default pallas; pallas_pipelined emits "
-                         "double-buffered async copies, interpret "
-                         "fallback on CPU)")
     ap.add_argument("--no-compiled", action="store_true",
                     help="skip the jitted compiled lane on CPU hosts")
     ap.add_argument("--fit", action="store_true",
@@ -488,7 +470,6 @@ def main(argv=None) -> int:
     kernels = set(args.kernels.split(",")) if args.kernels else None
     doc = measure_all(kernels=kernels, reps=args.reps, n=args.n,
                       schedules=tuple(args.schedules.split(",")),
-                      backend=args.backend,
                       compiled=not args.no_compiled)
     args.out.parent.mkdir(parents=True, exist_ok=True)
     args.out.write_text(json.dumps(doc, indent=1) + "\n")

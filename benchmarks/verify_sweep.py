@@ -11,7 +11,7 @@ statement orders with ``repro.verify``:
   tilable programs — the Pallas source;
 * per rule set: **rule soundness** (random/bf16/adversarial
   differential validation);
-* per (kernel, schedule mode, Pallas emitter): the **grid pass** (PR 9)
+* per (kernel, schedule mode): the **grid pass** (PR 9)
   — the emitted kernel's ``plan_tile_call`` launch plan is certified
   coverage-complete, write-disjoint, in-bounds (padded remainder tile
   modeled) and inside the exact VMEM budget, at a geometry that forces
@@ -33,7 +33,7 @@ import sys
 from typing import Dict, List
 
 from repro.core import (SaturatorConfig, SearchConfig, compute_schedule,
-                        get_emitter, saturate_program)
+                        saturate_program)
 from repro.core.pallasgen import SyncPallasGenerator
 from repro.core.pipeline import _schedule_cm
 from repro.core.schedule import SCHEDULE_MODES
@@ -44,7 +44,6 @@ from repro.verify import (VerifyReport, check_egraph, check_generated,
 from repro.verify.grid_check import check_tile_kernel_grid
 
 RULE_SETS = ("paper", "extended")
-GRID_EMITTERS = ("pallas", "pallas_pipelined")
 
 
 def _config(rule_set: str) -> SaturatorConfig:
@@ -96,17 +95,16 @@ def sweep(kernels: List[str]) -> Dict:
                 kfs.extend(check_generated(pk.source, shapes_of(prog),
                                            subject=f"{kname}:pallas"))
                 report.sources_checked += 1
-                # grid pass: one emission per (schedule mode, emitter)
-                # reuses the saturation above — geometry certification
-                # needs only the emitted kernel, not a fresh pipeline run
-                for mode, emitter in ((m, e) for m in SCHEDULE_MODES
-                                      for e in GRID_EMITTERS):
-                    epk = get_emitter(emitter).emit(
+                # grid pass: one emission per schedule mode reuses the
+                # saturation above — geometry certification needs only
+                # the emitted kernel, not a fresh pipeline run
+                for mode in SCHEDULE_MODES:
+                    epk = SyncPallasGenerator(
                         sk.ssa, sk.extraction, bulk=True,
-                        schedule=scheds[mode])
+                        schedule=scheds[mode]).generate_pallas()
                     gres = check_tile_kernel_grid(epk, prog)
                     kfs.extend(dataclasses.replace(
-                        f, subject=f"{mode}/{emitter}:{f.subject}")
+                        f, subject=f"{mode}/pallas:{f.subject}")
                         for f in gres.findings)
                     grids += gres.grids_checked
             report.extend(kfs)

@@ -17,7 +17,8 @@ the verifier can reason about:
 * :class:`BlockAccess` / :class:`GridModel` — the declarative model of
   one ``pallas_call``: grid extents, per-operand block shapes, buffer
   shapes (post-padding), index maps, element byte widths, and the VMEM
-  buffer multiplicity (2 for double-buffered async staging).
+  buffer multiplicity (2: compiled Pallas double-buffers every blocked
+  operand).
 
 Footprints use Pallas *blocked* indexing semantics: an index map returns
 block coordinates, so instance ``g`` touches elements
@@ -261,8 +262,7 @@ class BlockAccess:
     call — i.e. *after* any host-side padding (``_ceil_to``), so the
     pad region is modeled explicitly as in-bounds. ``buffers`` is the
     VMEM copy count: 2 for a blocked operand, which compiled Pallas
-    double-buffers across grid steps, 3 when the pipelined emitter also
-    stages it through a scratch buffer.
+    double-buffers across grid steps.
     """
     array: str
     mode: str                       # "read" | "write"

@@ -15,8 +15,8 @@ from .emit import EMITTER_NAMES, Emitter, EmitterInfo, get_emitter
 from .ir import ENode
 from .jaxpr_bridge import BridgeUnsupported, maybe_saturate, saturate_jax_fn
 from .pallasgen import (PallasGenerator,  # deprecated-ok (re-export)
-                        PipelinedPallasGenerator, SyncPallasGenerator,
-                        TileOp, make_tile_op, pick_row_block)
+                        SyncPallasGenerator, TileOp, make_tile_op,
+                        pick_row_block)
 from .pipeline import (CACHE_ENV_VAR, MODES, VERIFY_ENV_VAR, CacheConfig,
                        SaturatedKernel, SaturatorConfig, ScheduleConfig,
                        SearchConfig, VerifyConfig, saturate_all_modes,
@@ -37,7 +37,7 @@ __all__ = [
     "BeamStats", "beam_search", "optimality_gap",
     "BridgeUnsupported", "maybe_saturate", "saturate_jax_fn",
     "EMITTER_NAMES", "Emitter", "EmitterInfo", "get_emitter",
-    "PallasGenerator", "SyncPallasGenerator", "PipelinedPallasGenerator",
+    "PallasGenerator", "SyncPallasGenerator",
     "TileOp", "make_tile_op", "pick_row_block", "MODES", "VERIFY_ENV_VAR",
     "SearchConfig", "ScheduleConfig", "CacheConfig", "VerifyConfig",
     "SaturatedKernel", "SaturatorConfig", "saturate_all_modes",

@@ -2,17 +2,14 @@
 
 Before this module the two emission paths were a subclass fork
 (``CodeGenerator`` for JAX source, ``PallasGenerator`` for Pallas kernel
-bodies) that callers imported directly; adding the pipelined Pallas
-backend would have meant a third ad-hoc class name in every call site.
-Instead, emitters are now named:
+bodies) that callers imported directly. Instead, emitters are now named:
 
-====================  =============================  ==================
-name                  generator                      produces
-====================  =============================  ==================
-``jax``               :class:`JaxCodeGenerator`      ``GeneratedKernel``
-``pallas``            :class:`SyncPallasGenerator`   ``PallasKernel``
-``pallas_pipelined``  :class:`PipelinedPallasGenerator`  ``PallasKernel``
-====================  =============================  ==================
+==========  =============================  ==================
+name        generator                      produces
+==========  =============================  ==================
+``jax``     :class:`JaxCodeGenerator`      ``GeneratedKernel``
+``pallas``  :class:`SyncPallasGenerator`   ``PallasKernel``
+==========  =============================  ==================
 
 ``get_emitter(name)`` returns a small :class:`Emitter` facade; its
 ``emit(ssa, extraction, **options)`` classmethod builds the generator
@@ -29,12 +26,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
-EMITTER_NAMES: Tuple[str, ...] = ("jax", "pallas", "pallas_pipelined")
+EMITTER_NAMES: Tuple[str, ...] = ("jax", "pallas")
 
 # Bump an emitter's version whenever its emitted source for a fixed
 # (choice, schedule) changes: non-default emitters carry name@version in
 # the cache config fingerprint, so cached replays never mix emitters.
-_VERSIONS: Dict[str, int] = {"jax": 1, "pallas": 1, "pallas_pipelined": 1}
+_VERSIONS: Dict[str, int] = {"jax": 1, "pallas": 1}
 
 # Emitters whose cache entries predate the registry: their fingerprints
 # must stay byte-identical, so they contribute *no* emitter key (None).
@@ -89,20 +86,9 @@ class _PallasEmitter(Emitter):
         return SyncPallasGenerator
 
 
-class _PipelinedPallasEmitter(Emitter):
-    info = EmitterInfo("pallas_pipelined", _VERSIONS["pallas_pipelined"],
-                       "pallas")
-
-    @property
-    def generator_cls(self):
-        from .pallasgen import PipelinedPallasGenerator
-        return PipelinedPallasGenerator
-
-
 _REGISTRY: Dict[str, Emitter] = {
     "jax": _JaxEmitter(),
     "pallas": _PallasEmitter(),
-    "pallas_pipelined": _PipelinedPallasEmitter(),
 }
 
 

@@ -19,41 +19,30 @@ The companion ``make_tile_op`` wrapper builds a jitted op that reshapes
 reshapes back. On CPU it runs in interpret mode (kernel body executed in
 Python) — bit-identical semantics, used by all tests.
 
-Since PR 8 two Pallas emitters exist (see :mod:`repro.core.emit`):
-
-* ``"pallas"`` — :class:`SyncPallasGenerator`, the synchronous emitter
-  described above (known as ``PallasGenerator`` before the registry);
-* ``"pallas_pipelined"`` — :class:`PipelinedPallasGenerator`, which turns
-  the schedule's load→first-consumer overlap windows into explicit
-  double-buffered ``pltpu.make_async_copy`` start/wait pairs: the copy
-  *starts* at the load's scheduled slot and the matching *wait* lands at
-  the first consumer (or earlier, when its semaphore parity is needed for
-  a later copy — the classic two-deep double-buffer discipline). Its
-  interpret-mode fallback degrades to the synchronous emitter
-  bit-identically.
+The generator is the registry's ``"pallas"`` emitter (see
+:mod:`repro.core.emit`), :class:`SyncPallasGenerator` — known as
+``PallasGenerator`` before the registry. Compiled Pallas double-buffers
+every blocked operand across grid steps on its own, so the HBM→VMEM
+copy of the next row block overlaps the current one's compute.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.runtime import chaos
 
-from .codegen import JaxCodeGenerator, GenStats, _PRELUDE, _sanitize
+from .codegen import JaxCodeGenerator, GenStats, _PRELUDE
 from .dsl import KernelProgram
-from .extract import ExtractionResult
 from .pipeline import SaturatorConfig, saturate_program
-from .schedule import compute_schedule
-from .ssa import LoopRegion, Region, SSAResult, StoreEffect
+from .ssa import LoopRegion, Region, StoreEffect
 from .hardware import DEFAULT_CHIP
 
 # Kernel bodies compute in f32 whatever the operands store: every load is
@@ -64,28 +53,6 @@ _UPCAST = ".astype(jnp.float32)"
 
 def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
-
-
-@dataclasses.dataclass
-class AsyncCopy:
-    """One pipelined load: an async HBM/VMEM copy start/wait pair.
-
-    ``start_slot``/``wait_slot`` are positions in the region's scheduled
-    unit order (``ScheduleResult.ordered_units``); the verifier certifies
-    the pairing against these (every start has exactly one wait, the wait
-    dominates the first buffer read, semaphore parity alternates)."""
-    index: int          # emission order (0, 1, ...) — _cp{index} in source
-    array: str          # source array name (copies {array}_ref -> {array}_buf)
-    buf: str            # destination scratch buffer parameter name
-    sem: int            # semaphore parity: index % 2 (double buffering)
-    cid: int            # load e-class the copy materializes
-    start_slot: int     # scheduled unit slot where the copy starts
-    wait_slot: int = -1  # slot whose emission waited the copy (-1 = pending)
-
-    def to_doc(self) -> Dict[str, Any]:
-        return {"index": self.index, "array": self.array, "buf": self.buf,
-                "sem": self.sem, "start_slot": self.start_slot,
-                "wait_slot": self.wait_slot}
 
 
 @dataclasses.dataclass
@@ -101,16 +68,7 @@ class PallasKernel:
     bulk: bool
     schedule_mode: str = "bulk"
     schedule: Optional[Any] = None   # ScheduleResult for explicit orders
-    # -- PR-8 emitter metadata -------------------------------------------
     emitter: str = "pallas"          # registry name that produced this
-    # pipelined emitter only: arrays with an async copy, in the order the
-    # body's scratch buffer parameters appear (drives scratch_shapes)
-    async_arrays: Tuple[str, ...] = ()
-    async_plan: Tuple[AsyncCopy, ...] = ()
-    # synchronous interpret-mode fallback (bit-identical to the "pallas"
-    # emitter under the same schedule); None for the sync emitter itself
-    fallback_source: Optional[str] = None
-    fallback_body: Optional[Callable] = None
 
 
 class SyncPallasGenerator(JaxCodeGenerator):
@@ -118,15 +76,6 @@ class SyncPallasGenerator(JaxCodeGenerator):
     of a jnp function. Known as ``PallasGenerator`` before the PR-8
     emitter registry (:mod:`repro.core.emit`); that name remains as a
     deprecated alias."""
-
-    def __init__(self, ssa: SSAResult, extraction: ExtractionResult, *,
-                 bulk: bool = True, fn_name: Optional[str] = None,
-                 reuse_temps: bool = True, schedule=None,
-                 sched_cost_model=None):
-        super().__init__(ssa, extraction, bulk=bulk, fn_name=fn_name,
-                         reuse_temps=reuse_temps, schedule=schedule,
-                         sched_cost_model=sched_cost_model)
-        self._extraction = extraction
 
     def _check_tilable(self):
         def walk(region: Region):
@@ -183,15 +132,6 @@ class SyncPallasGenerator(JaxCodeGenerator):
         self.scope.bind_sym(eff.version_out, dst_ref)
         self.stats.n_stores += 1
 
-    # hooks the pipelined subclass specializes ---------------------------
-    def _prelude(self) -> str:
-        return _PRELUDE
-
-    def _body_params(self, ref_params: List[str]) -> List[str]:
-        """Positional parameters before the scalars (pipelined emission
-        appends scratch buffers + DMA semaphores here)."""
-        return ref_params
-
     def generate_pallas(self) -> PallasKernel:
         self._check_tilable()
         prog = self.ssa.prog
@@ -213,21 +153,16 @@ class SyncPallasGenerator(JaxCodeGenerator):
             self._collect_load_regions()
         self.emit_region(self.ssa.region, (), lines, indent)
         body = "\n".join(lines) if lines else "    pass"
-        sig = ", ".join(self._body_params(ref_params) + scalars)
-        src = (f"{self._prelude()}\n"
+        sig = ", ".join(ref_params + scalars)
+        src = (f"{_PRELUDE}\n"
                f"def {self.fn_name}_body({sig}):\n{body}\n")
         glb: Dict[str, Any] = {}
         chaos.maybe_raise("exec_fail", prog.name,
                           "generated Pallas source")
         exec(compile(src, f"<pallas:{self.fn_name}>", "exec"), glb)
-        return self._finalize_kernel(
-            src, glb[f"{self.fn_name}_body"], in_arrays, out_arrays,
-            scalars, sched)
-
-    def _finalize_kernel(self, src, body_fn, in_arrays, out_arrays,
-                         scalars, sched) -> PallasKernel:
         return PallasKernel(
-            name=self.fn_name, source=src, kernel_body=body_fn,
+            name=self.fn_name, source=src,
+            kernel_body=glb[f"{self.fn_name}_body"],
             in_arrays=in_arrays, weight_arrays=[], out_arrays=out_arrays,
             scalars=scalars, stats=self.stats, bulk=self.bulk,
             schedule_mode=self.schedule_mode, schedule=sched)
@@ -246,174 +181,6 @@ class PallasGenerator(SyncPallasGenerator):
             "repro.core.emit.get_emitter('pallas') or SyncPallasGenerator",
             DeprecationWarning, stacklevel=2)
         super().__init__(*args, **kwargs)
-
-
-_PIPELINED_PRELUDE = _PRELUDE + """
-from jax.experimental.pallas import tpu as pltpu
-"""
-
-
-class PipelinedPallasGenerator(SyncPallasGenerator):
-    """The ``"pallas_pipelined"`` emitter: double-buffered async copies.
-
-    Every whole-tile load of an *input* ref becomes an explicit
-    ``pltpu.make_async_copy({a}_ref, {a}_buf, _sem{k%2})`` whose
-    ``.start()`` is emitted at the load's scheduled slot and whose
-    ``.wait()`` lands at the first consumer — the textual realization of
-    the overlap window ``ScheduleResult.load_windows`` prices. Two DMA
-    semaphores are rotated (``index % 2``); starting a copy on a parity
-    that is still in flight first drains it, bounding outstanding copies
-    to two, the double-buffer invariant the verifier certifies.
-
-    Emission *always* follows an explicit :class:`ScheduleResult` (named
-    source/bulk orders are reconstructed searchlessly when no cost
-    schedule is attached) so every load has a well-defined slot. The
-    kernel also carries a synchronous fallback body — generated by
-    :class:`SyncPallasGenerator` under the *same* schedule, hence
-    bit-identical to the ``"pallas"`` emitter — which the interpret path
-    (CPU) executes.
-    """
-
-    EMITTER_NAME = "pallas_pipelined"
-
-    def __init__(self, *args, **kwargs):
-        super().__init__(*args, **kwargs)
-        self._async_copies: List[AsyncCopy] = []
-        self._pending: Dict[int, AsyncCopy] = {}   # load cid -> copy
-        self._waited: Dict[int, AsyncCopy] = {}    # waited, not yet read
-        self._inflight: Dict[int, AsyncCopy] = {}  # sem parity -> copy
-        self._slot = -1
-
-    def _prelude(self) -> str:
-        return _PIPELINED_PRELUDE
-
-    def _body_params(self, ref_params: List[str]) -> List[str]:
-        bufs = [c.buf for c in self._async_copies]
-        sems = ["_sem0", "_sem1"] if self._async_copies else []
-        return ref_params + bufs + sems
-
-    def _resolve_schedule(self):
-        sched = super()._resolve_schedule()
-        if sched is None:
-            # named legacy order: reconstruct it explicitly (searchless,
-            # bit-identical to the legacy emission) so every load has a
-            # scheduled slot to hang its copy start on
-            cm = self._sched_cm if hasattr(self._sched_cm, "latency") \
-                else None
-            if cm is not None and hasattr(cm, "bind_egraph"):
-                cm.bind_egraph(self.eg)
-            self._explicit = compute_schedule(
-                self.ssa, self.choice, mode=self.schedule_mode,
-                cost_model=cm, move_budget=0)
-        return self._explicit
-
-    # -- async copy placement -------------------------------------------
-    def _pipelineable(self, cid: int) -> Optional[str]:
-        """The input array name when the load can become an async copy
-        (whole-tile load of an ``*_ref`` input), else None."""
-        cid = self.eg.find(cid)
-        if self.scope.get(cid) is not None:
-            return None   # already materialized
-        n = self.node(cid)
-        if n.op != "load" or len(n.children) != 1:
-            return None
-        arr_n = self.node(n.children[0])
-        if arr_n.op != "array":
-            return None
-        bound = self.scope.get_sym(arr_n.payload)
-        if bound is None or not bound.endswith("_ref"):
-            return None   # re-read of a written oref: keep synchronous
-        return bound[:-len("_ref")]
-
-    def _start_copy(self, cid: int, arr: str, lines: List[str],
-                    indent: str):
-        cid = self.eg.find(cid)
-        k = len(self._async_copies)
-        parity = k % 2
-        # double-buffer discipline: at most one copy in flight per
-        # semaphore — drain the previous same-parity copy before reusing
-        prev = self._inflight.get(parity)
-        if prev is not None and prev.wait_slot < 0:
-            lines.append(f"{indent}_cp{prev.index}.wait()")
-            prev.wait_slot = self._slot
-            self._waited[self.eg.find(prev.cid)] = \
-                self._pending.pop(self.eg.find(prev.cid))
-        cp = AsyncCopy(index=k, array=arr, buf=f"{arr}_buf", sem=parity,
-                       cid=cid, start_slot=self._slot)
-        lines.append(f"{indent}_cp{k} = pltpu.make_async_copy("
-                     f"{arr}_ref, {arr}_buf, _sem{parity})")
-        lines.append(f"{indent}_cp{k}.start()")
-        self._async_copies.append(cp)
-        self._pending[cid] = cp
-        self._inflight[parity] = cp
-
-    def emit_value(self, cid: int, lines: List[str], indent: str) -> str:
-        cid = self.eg.find(cid)
-        cp = self._pending.pop(cid, None) or self._waited.pop(cid, None)
-        if cp is not None:
-            if cp.wait_slot < 0:
-                lines.append(f"{indent}_cp{cp.index}.wait()")
-                cp.wait_slot = self._slot
-            if self._inflight.get(cp.sem) is cp:
-                del self._inflight[cp.sem]
-            name = self._fresh()
-            self.stats.n_temps += 1
-            self.stats.n_loads += 1
-            self.stats.instruction_mix["load"] = \
-                self.stats.instruction_mix.get("load", 0) + 1
-            lines.append(f"{indent}{name} = {cp.buf}[...]{_UPCAST}")
-            self.scope.bind(cid, name)
-            return name
-        return super().emit_value(cid, lines, indent)
-
-    def _emit_scheduled(self, sched, path, lines, indent):
-        for u in sched.ordered_units():
-            self._slot += 1
-            if u.kind == "load":
-                arr = self._pipelineable(u.cid)
-                if arr is not None:
-                    self._start_copy(u.cid, arr, lines, indent)
-                else:
-                    self.emit_value(u.cid, lines, indent)
-                if not self._region_first_compute.get(path, False):
-                    self.stats.loads_before_compute += 1
-            elif u.kind == "compute":
-                self.emit_value(u.cid, lines, indent)
-                self._region_first_compute[path] = True
-            elif u.kind == "store":
-                self._emit_store(u.item, lines, indent)
-                self._region_first_compute[path] = True
-            else:
-                self._emit_loop(u.item, path, lines, indent)
-                self._region_first_compute[path] = True
-        # drain copies the region never consumed (defensive: keeps the
-        # start/wait pairing total even for dead loads)
-        self._slot += 1
-        for cid, cp in list(self._pending.items()):
-            lines.append(f"{indent}_cp{cp.index}.wait()")
-            cp.wait_slot = self._slot
-            self._waited[cid] = self._pending.pop(cid)
-            if self._inflight.get(cp.sem) is cp:
-                del self._inflight[cp.sem]
-
-    def _finalize_kernel(self, src, body_fn, in_arrays, out_arrays,
-                         scalars, sched) -> PallasKernel:
-        # the interpret-mode fallback: the synchronous emitter run under
-        # the *same* resolved schedule — bit-identical to "pallas"
-        sync = SyncPallasGenerator(
-            self.ssa, self._extraction, bulk=self.bulk,
-            fn_name=self.fn_name, reuse_temps=self.reuse_temps,
-            schedule=sched, sched_cost_model=self._sched_cm)
-        fb = sync.generate_pallas()
-        return PallasKernel(
-            name=self.fn_name, source=src, kernel_body=body_fn,
-            in_arrays=in_arrays, weight_arrays=[], out_arrays=out_arrays,
-            scalars=scalars, stats=self.stats, bulk=self.bulk,
-            schedule_mode=self.schedule_mode, schedule=sched,
-            emitter=self.EMITTER_NAME,
-            async_arrays=tuple(c.array for c in self._async_copies),
-            async_plan=tuple(self._async_copies),
-            fallback_source=fb.source, fallback_body=fb.kernel_body)
 
 
 @dataclasses.dataclass
@@ -545,12 +312,6 @@ def plan_tile_call(pk: PallasKernel, in_shapes: Sequence[Tuple[int, ...]],
 
 def _apply_tile_op(op: TileOp, arrays, scalar_items, interpret: bool):
     pk = op.pk
-    # pipelined kernels carry a synchronous twin: interpret mode runs it
-    # — bit-identical to the "pallas" emitter — while the compiled path
-    # gets the async body
-    use_async = (pk.fallback_body is None
-                 or (not interpret and bool(pk.async_arrays)))
-    body_fn = pk.kernel_body if use_async else pk.fallback_body
     lead = arrays[0]
     d = max(a.shape[-1] for a in arrays)
     rb = op.block_rows(d, max(jnp.dtype(a.dtype).itemsize for a in arrays))
@@ -578,28 +339,19 @@ def _apply_tile_op(op: TileOp, arrays, scalar_items, interpret: bool):
 
     def body(*refs):
         if not names:
-            return body_fn(*refs)
+            return pk.kernel_body(*refs)
         s_ref = refs[n_in]
-        body_fn(*refs[:n_in], *refs[n_in + 1:],
+        pk.kernel_body(*refs[:n_in], *refs[n_in + 1:],
                 **{n: s_ref[i] for i, n in enumerate(names)})
 
-    block_shapes = {e.name: e.block_shape for e in plan.inputs}
     out_specs = [pl.BlockSpec(e.block_shape, e.index_map)
                  for e in plan.outputs]
     out_shapes = [jax.ShapeDtypeStruct(e.buffer_shape, lead.dtype)
                   for e in plan.outputs]
-    scratch_shapes = ()
-    if use_async and pk.async_arrays:
-        # one VMEM staging buffer per pipelined input (block-shaped) plus
-        # the two rotating DMA-completion semaphores
-        scratch_shapes = [pltpu.VMEM(block_shapes[a], lead.dtype)
-                          for a in pk.async_arrays]
-        scratch_shapes += [pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA]
     call = pl.pallas_call(
         body, grid=plan.grid, in_specs=in_specs,
         out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
         out_shape=out_shapes if len(out_shapes) > 1 else out_shapes[0],
-        scratch_shapes=scratch_shapes,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=DEFAULT_CHIP.vmem_limit_bytes),
         name=f"tile_{op.name}", interpret=interpret)
@@ -665,15 +417,13 @@ def make_tile_op(prog: KernelProgram,
     """Saturate ``prog`` and build both the Pallas op and its jnp oracle.
 
     The Pallas emitter is picked by ``config.emitter`` through the PR-8
-    registry (:mod:`repro.core.emit`): ``None``/``"pallas"`` keeps the
-    synchronous emitter, ``"pallas_pipelined"`` emits double-buffered
-    async copies (with a bit-identical interpret fallback)."""
+    registry (:mod:`repro.core.emit`); ``None`` means ``"pallas"``."""
     cfg = config or SaturatorConfig(mode="accsat", cost_model="tpu_v5e")
     sk = saturate_program(prog, cfg)
     # emission follows the configuration that actually *built* sk: a
     # ladder-degraded build (repro.runtime.guard) carries its cheap
-    # config in sk.config, and re-running the full schedule search /
-    # pipelined emitter here would re-hit whatever failed
+    # config in sk.config, and re-running the full schedule search here
+    # would re-hit whatever failed
     ecfg = sk.config
     from .emit import get_emitter
     emitter = get_emitter(ecfg.emitter or "pallas")

@@ -100,10 +100,9 @@ class ScheduleConfig:
     extraction; always prices the cost schedule search.
 
     ``emitter`` (repro.core.emit): registry name of the emission
-    backend. None keeps the context's default ("jax" in the pipeline,
-    "pallas" in make_tile_op); "pallas_pipelined" emits double-buffered
-    async copies. Non-default emitters enter the cache fingerprint as
-    ``name@v{version}`` so cached replays never mix emitters."""
+    backend, ``"jax"`` or ``"pallas"``. None keeps the context's default
+    ("jax" in the pipeline, "pallas" in make_tile_op). Both are default
+    emitters, so neither enters the cache fingerprint."""
     schedule: Optional[str] = None
     device_profile: Optional[Any] = None
     emitter: Optional[str] = None

@@ -251,9 +251,9 @@ def get_tile_op(name: str, mode: str = "accsat",
     way, so the *selected term* is identical across schedules; only the
     emission order moves. ``device_profile`` prices the cost-driven
     schedule search with a calibrated model (name/path of a profile
-    under ``experiments/device_profiles/``). ``emitter`` selects the
-    Pallas emission backend (``"pallas" | "pallas_pipelined"``, see
-    :mod:`repro.core.emit`; None = synchronous ``"pallas"``).
+    under ``experiments/device_profiles/``). ``emitter`` names the
+    emission backend (see :mod:`repro.core.emit`; None = ``"pallas"``,
+    the one Pallas emitter).
 
     ``cache_dir`` (see :mod:`repro.cache`) persists the saturation
     result on disk: this ``lru_cache`` only amortizes within a process,

@@ -39,8 +39,7 @@ from .grid_check import (GridCheckResult, check_grid, check_tile_op,
                          flash_attention_model, ssd_scan_model,
                          tile_call_model)
 from .rules_check import RuleRecord, RulesCheckResult, verify_rules
-from .schedule_check import (ScheduleCheckResult, verify_async_plan,
-                             verify_schedule)
+from .schedule_check import ScheduleCheckResult, verify_schedule
 
 VERIFY_LEVELS = ("off", "cheap", "full")
 
@@ -50,7 +49,7 @@ __all__ = [
     "PASS_GRID",
     "verify_rules", "RulesCheckResult", "RuleRecord",
     "check_egraph", "verify_schedule", "ScheduleCheckResult",
-    "verify_async_plan", "check_generated", "shapes_of",
+    "check_generated", "shapes_of",
     "check_grid", "check_tile_op", "tile_call_model", "GridCheckResult",
     "flash_attention_model", "ssd_scan_model",
     "verify_saturated", "verify_pallas_kernel", "verify_tile_op",
@@ -121,24 +120,12 @@ def verify_saturated(sk, level: Optional[str] = None) -> VerifyReport:
 
 
 def verify_pallas_kernel(pk, ssa) -> VerifyReport:
-    """Certify one emitted :class:`PallasKernel` (PR 8).
-
-    Lints the kernel source (and, for the pipelined emitter, the
-    synchronous fallback source under the ``:fallback`` subject — the
-    async-pairing checks in :mod:`.codegen_check` run on both), then
-    cross-checks the recorded async-copy plan against the schedule with
-    :func:`verify_async_plan`: start slots, wait domination, semaphore
-    parity and the ≤2-in-flight double-buffer bound."""
+    """Certify one emitted :class:`PallasKernel` (PR 8): lint its
+    kernel source with the :mod:`.codegen_check` pass."""
     rep = VerifyReport()
-    shapes = shapes_of(ssa.prog)
-    rep.extend(check_generated(pk.source, shapes, subject=pk.name))
+    rep.extend(check_generated(pk.source, shapes_of(ssa.prog),
+                               subject=pk.name))
     rep.sources_checked += 1
-    if pk.fallback_source is not None:
-        rep.extend(check_generated(pk.fallback_source, shapes,
-                                   subject=f"{pk.name}:fallback"))
-        rep.sources_checked += 1
-    if pk.async_plan and pk.schedule is not None:
-        rep.extend(verify_async_plan(ssa, pk.schedule, pk.async_plan))
     from repro.core.telemetry import telemetry
     telemetry().record_verify(rep)
     return rep

@@ -24,30 +24,21 @@ read the wrong tile:
 * **dead loads** (``warning``) — a ``_vN`` load temp never consumed;
 * **memory-access order** (``info``) — the overlap-distance lint: loads
   whose first consumer is the immediately following statement leave the
-  scheduler no latency to hide (one aggregated note per function);
-* **async copy pairing** (``error``) — for the PR-8 pipelined Pallas
-  emitter: every ``pltpu.make_async_copy`` start has exactly one wait
-  (``unmatched-async-start`` / ``unmatched-async-wait``), the wait
-  dominates the first read of the destination buffer
-  (``async-wait-order``), semaphore parity alternates with copy index
-  (``async-buffer-parity``), and no two copies share a semaphore while
-  in flight (``async-sem-overlap``) — the double-buffer invariant.
+  scheduler no latency to hide (one aggregated note per function).
 """
 from __future__ import annotations
 
 import ast
 import re
-from typing import Any, Dict, List, Optional, Set, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
 from .findings import PASS_CODEGEN, Finding
 
 Shape = Optional[Tuple[Optional[int], ...]]
 
 _TEMP_RE = re.compile(r"_v\d+$")
-_CP_RE = re.compile(r"_cp(\d+)$")
-_SEM_RE = re.compile(r"_sem(\d+)$")
 _GLOBALS = {
-    "jax", "jnp", "lax", "pltpu", "_rothalf", "_calls",
+    "jax", "jnp", "lax", "_rothalf", "_calls",
     "True", "False", "None", "range", "len", "float", "int", "tuple",
 }
 
@@ -59,11 +50,10 @@ def shapes_of(prog) -> Dict[str, Shape]:
 
 def _base_array(name: str, shapes: Dict[str, Shape]) -> Optional[str]:
     """Resolve a source identifier to a declared array (Pallas refs
-    strip their ``_ref``/``_oref`` suffix, pipelined staging buffers
-    their ``_buf``)."""
+    strip their ``_ref``/``_oref`` suffix)."""
     if name in shapes:
         return name
-    for suf in ("_oref", "_ref", "_buf"):
+    for suf in ("_oref", "_ref"):
         if name.endswith(suf) and name[: -len(suf)] in shapes:
             return name[: -len(suf)]
     return None
@@ -170,7 +160,6 @@ def _shape_env(fn: ast.FunctionDef,
         env[name] = shp
         env[f"{name}_ref"] = shp
         env[f"{name}_oref"] = shp
-        env[f"{name}_buf"] = shp
     changed = True
     while changed:                       # aliases of aliases
         changed = False
@@ -344,109 +333,4 @@ def _check_fn(fn: ast.FunctionDef, shapes: Dict[str, Shape],
             f"the immediately following statement (no latency-hiding "
             f"distance)", subject=tag))
 
-    out.extend(_check_async(fn, tag))
-    return out
-
-
-# -- async copy pairing (pipelined Pallas emitter) ----------------------------
-def _check_async(fn: ast.FunctionDef, tag: str) -> List[Finding]:
-    """Certify the double-buffered async-copy discipline of a pipelined
-    Pallas body: exactly one wait per start, waits dominating the first
-    destination-buffer read, ``index % 2`` semaphore parity, and at most
-    one copy in flight per semaphore."""
-    out: List[Finding] = []
-    copies: Dict[int, Dict[str, Any]] = {}
-    buf_first_read: Dict[str, int] = {}
-    for pos, st in enumerate(fn.body):
-        if (isinstance(st, ast.Assign) and len(st.targets) == 1
-                and isinstance(st.targets[0], ast.Name)):
-            m = _CP_RE.match(st.targets[0].id)
-            val = st.value
-            if (m and isinstance(val, ast.Call)
-                    and isinstance(val.func, ast.Attribute)
-                    and val.func.attr == "make_async_copy"):
-                k = int(m.group(1))
-                sem = None
-                if len(val.args) >= 3 and isinstance(val.args[2], ast.Name):
-                    sm = _SEM_RE.match(val.args[2].id)
-                    sem = int(sm.group(1)) if sm else None
-                buf = (val.args[1].id if len(val.args) >= 2
-                       and isinstance(val.args[1], ast.Name) else None)
-                copies[k] = {"pos": pos, "start": None, "waits": [],
-                             "buf": buf, "sem": sem}
-                continue
-        if (isinstance(st, ast.Expr) and isinstance(st.value, ast.Call)
-                and isinstance(st.value.func, ast.Attribute)
-                and isinstance(st.value.func.value, ast.Name)
-                and st.value.func.attr in ("start", "wait")):
-            m = _CP_RE.match(st.value.func.value.id)
-            if m:
-                k = int(m.group(1))
-                if k not in copies:
-                    out.append(Finding(
-                        PASS_CODEGEN, "error", "unmatched-async-wait",
-                        f"_cp{k}.{st.value.func.attr}() at statement "
-                        f"{pos} has no matching make_async_copy",
-                        subject=f"{tag}:_cp{k}"))
-                elif st.value.func.attr == "start":
-                    copies[k]["start"] = pos
-                else:
-                    copies[k]["waits"].append(pos)
-                continue
-        for nm in _loads_outside_nested(st):
-            if nm.id.endswith("_buf"):
-                buf_first_read.setdefault(nm.id, pos)
-    for k in sorted(copies):
-        c = copies[k]
-        subj = f"{tag}:_cp{k}"
-        if c["sem"] is not None and c["sem"] != k % 2:
-            out.append(Finding(
-                PASS_CODEGEN, "error", "async-buffer-parity",
-                f"async copy _cp{k} uses _sem{c['sem']}; double "
-                f"buffering requires parity _sem{k % 2}", subject=subj))
-        if c["start"] is None:
-            out.append(Finding(
-                PASS_CODEGEN, "error", "unmatched-async-wait",
-                f"async copy _cp{k} is created but never started",
-                subject=subj))
-        if not c["waits"]:
-            out.append(Finding(
-                PASS_CODEGEN, "error", "unmatched-async-start",
-                f"async copy _cp{k} ({c['buf']}) is started but never "
-                f"waited — its buffer contents are undefined at use",
-                subject=subj))
-            continue
-        if len(c["waits"]) > 1:
-            out.append(Finding(
-                PASS_CODEGEN, "error", "unmatched-async-wait",
-                f"async copy _cp{k} is waited {len(c['waits'])} times",
-                subject=subj))
-        w = c["waits"][0]
-        if c["start"] is not None and w <= c["start"]:
-            out.append(Finding(
-                PASS_CODEGEN, "error", "async-wait-order",
-                f"async copy _cp{k} waits at statement {w}, before its "
-                f"start at {c['start']}", subject=subj))
-        first_read = buf_first_read.get(c["buf"] or "")
-        if first_read is not None and first_read < w:
-            out.append(Finding(
-                PASS_CODEGEN, "error", "async-wait-order",
-                f"{c['buf']} is read at statement {first_read} before "
-                f"_cp{k}.wait() at {w} — the wait must dominate the "
-                f"first use", subject=subj))
-    done = sorted(k for k in copies
-                  if copies[k]["start"] is not None and copies[k]["waits"])
-    for i, k1 in enumerate(done):
-        for k2 in done[i + 1:]:
-            if copies[k1]["sem"] is None or \
-                    copies[k1]["sem"] != copies[k2]["sem"]:
-                continue
-            if copies[k2]["start"] < copies[k1]["waits"][0]:
-                out.append(Finding(
-                    PASS_CODEGEN, "error", "async-sem-overlap",
-                    f"async copies _cp{k1} and _cp{k2} are both in "
-                    f"flight on _sem{copies[k1]['sem']} (start "
-                    f"{copies[k2]['start']} before wait "
-                    f"{copies[k1]['waits'][0]})",
-                    subject=f"{tag}:_sem{copies[k1]['sem']}"))
     return out

@@ -34,7 +34,7 @@ is not provable).
 
 Consumers: ``verify_tile_op`` (the ``verify=`` wiring in
 ``make_tile_op``), the grid-audit stage of ``benchmarks/verify_sweep.py``
-(13 tile kernels × schedules × emitters + the hand-written
+(13 tile kernels × schedules + the hand-written
 flash-attention / SSD-scan layouts), and the static legality pre-filter
 of ``benchmarks/tune.py``.
 """
@@ -325,18 +325,13 @@ def tile_input_shapes(pk, prog, rows: int, d: int) -> List[Tuple[int, ...]]:
     return shapes
 
 
-def tile_call_model(pk, plan, dtype_bytes: int = 4,
-                    pipelined: Optional[bool] = None) -> GridModel:
+def tile_call_model(pk, plan, dtype_bytes: int = 4) -> GridModel:
     """Convert one :func:`repro.core.pallasgen.plan_tile_call` plan into
     the checkable :class:`GridModel`. Every blocked operand is
-    double-buffered; ``pipelined`` adds the staging scratch buffer of the
-    kernel's ``async_plan`` arrays (default = whether it carries one)."""
-    pipelined = bool(pk.async_arrays) if pipelined is None else pipelined
-    async_set = set(pk.async_arrays) if pipelined else set()
+    double-buffered."""
     reads = tuple(
         BlockAccess(e.name, "read", e.block_shape, e.buffer_shape,
-                    e.index_map, dtype_bytes=dtype_bytes,
-                    buffers=3 if e.name in async_set else 2)
+                    e.index_map, dtype_bytes=dtype_bytes)
         for e in plan.inputs)
     writes = tuple(
         BlockAccess(e.name, "write", e.block_shape, e.buffer_shape,

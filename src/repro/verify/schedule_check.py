@@ -251,91 +251,3 @@ def _check_region(eg, node, path, items, rs, findings: List[Finding]):
                 f"its dependences: {deps_txt}",
                 subject=f"{region_tag}:{_unit_desc(u)}"))
 
-
-# -- pipelined emission plans (PR 8) ------------------------------------------
-def verify_async_plan(ssa: SSAResult, sched, plan) -> List[Finding]:
-    """Certify a pipelined Pallas emission plan against its schedule.
-
-    ``plan`` is the :class:`repro.core.pallasgen.AsyncCopy` sequence the
-    pipelined emitter recorded. Checks, per copy: the start sits at its
-    load's scheduled slot, the wait strictly follows the start and
-    dominates the load's first consumer, semaphore parity alternates
-    with copy index, and no semaphore carries two copies in flight (the
-    double-buffer invariant). Straight-line tile programs only — the
-    plan lives entirely in the root region."""
-    eg = ssa.egraph
-    out: List[Finding] = []
-    region = sched.regions.get(())
-    if region is None:
-        if plan:
-            out.append(Finding(
-                PASS_SCHEDULE, "error", "async-plan-region",
-                f"{len(plan)} async copies recorded but the schedule "
-                f"has no root region", subject="async-plan"))
-        return out
-    units = list(region.ordered_units())
-    load_slot: Dict[int, int] = {}
-    load_uid: Dict[int, int] = {}
-    for i, u in enumerate(units):
-        if u.kind == "load" and u.cid is not None:
-            load_slot[eg.find(u.cid)] = i
-            load_uid[eg.find(u.cid)] = u.uid
-    first_consumer: Dict[int, int] = {}
-    for i, u in enumerate(units):
-        for d in u.deps:
-            first_consumer.setdefault(d, i)
-    for cp in plan:
-        subj = f"async-plan:_cp{cp.index}"
-        if cp.sem != cp.index % 2:
-            out.append(Finding(
-                PASS_SCHEDULE, "error", "async-buffer-parity",
-                f"copy {cp.index} ({cp.array}) uses semaphore "
-                f"{cp.sem}; double buffering requires {cp.index % 2}",
-                subject=subj))
-        cid = eg.find(cp.cid)
-        slot = load_slot.get(cid)
-        if slot is None:
-            out.append(Finding(
-                PASS_SCHEDULE, "error", "async-start-slot",
-                f"copy {cp.index} ({cp.array}) has no matching load "
-                f"unit in the schedule", subject=subj))
-            continue
-        if cp.start_slot != slot:
-            out.append(Finding(
-                PASS_SCHEDULE, "error", "async-start-slot",
-                f"copy {cp.index} ({cp.array}) starts at slot "
-                f"{cp.start_slot}, but its load is scheduled at "
-                f"{slot}", subject=subj))
-        if cp.wait_slot < 0:
-            out.append(Finding(
-                PASS_SCHEDULE, "error", "unmatched-async-start",
-                f"copy {cp.index} ({cp.array}) was never waited",
-                subject=subj))
-            continue
-        if cp.wait_slot <= cp.start_slot:
-            out.append(Finding(
-                PASS_SCHEDULE, "error", "async-wait-order",
-                f"copy {cp.index} ({cp.array}) waits at slot "
-                f"{cp.wait_slot}, not after its start at "
-                f"{cp.start_slot}", subject=subj))
-        fc = first_consumer.get(load_uid[cid])
-        if fc is not None and cp.wait_slot > fc:
-            out.append(Finding(
-                PASS_SCHEDULE, "error", "async-wait-order",
-                f"copy {cp.index} ({cp.array}) waits at slot "
-                f"{cp.wait_slot}, after its first consumer at slot "
-                f"{fc} — the wait must dominate the first use",
-                subject=subj))
-    by_index = sorted(plan, key=lambda c: c.index)
-    for i, c1 in enumerate(by_index):
-        for c2 in by_index[i + 1:]:
-            if c1.sem != c2.sem or c1.wait_slot < 0:
-                continue
-            if c2.start_slot < c1.wait_slot:
-                out.append(Finding(
-                    PASS_SCHEDULE, "error", "async-sem-overlap",
-                    f"copies {c1.index} and {c2.index} are both in "
-                    f"flight on semaphore {c1.sem} (start "
-                    f"{c2.start_slot} before wait {c1.wait_slot})",
-                    subject=f"async-plan:sem{c1.sem}"))
-    return out

@@ -108,9 +108,9 @@ def test_unknown_kwarg_still_typeerror():
 def test_emitter_is_first_class_not_deprecated():
     with warnings.catch_warnings():
         warnings.simplefilter("error", DeprecationWarning)
-        cfg = SaturatorConfig(emitter="pallas_pipelined")
-    assert cfg.emitter == "pallas_pipelined"
-    assert cfg.schedule_cfg.emitter == "pallas_pipelined"
+        cfg = SaturatorConfig(emitter="pallas")
+    assert cfg.emitter == "pallas"
+    assert cfg.schedule_cfg.emitter == "pallas"
 
 
 def test_dataclasses_replace_on_groups():

@@ -1,14 +1,10 @@
-"""PR-8 emitter registry + pipelined Pallas emission.
+"""PR-8 emitter registry and the Pallas emitter.
 
 Covers the registry front door (``repro.core.emit``), the deprecated
-class aliases, the pipelined emitter's interpret-fallback bit-identity
-golden contract across every tile kernel, the async-plan verifier's
-mutation sensitivity, and the acceptance sweep (pipelined sources
-verify clean under both rule sets).
+class aliases, the agreement of the two emitters one extraction feeds
+(the Pallas kernel and its jnp twin) across every tile kernel, and the
+acceptance sweep (Pallas sources verify clean under both rule sets).
 """
-import dataclasses
-import warnings
-
 import numpy as np
 import pytest
 
@@ -19,14 +15,14 @@ from repro.core import (SaturatorConfig, ScheduleConfig, make_tile_op,
 from repro.core.emit import (EMITTER_NAMES, Emitter, emitter_cache_id,
                              get_emitter)
 from repro.kernels.tile_programs import PROGRAMS, get_tile_op
-from repro.verify import verify_async_plan, verify_pallas_kernel
+from repro.verify import verify_pallas_kernel
 
 TILE_NAMES = tuple(sorted(PROGRAMS))
 
 
 # -- registry ---------------------------------------------------------------
 def test_registry_names_and_targets():
-    assert EMITTER_NAMES == ("jax", "pallas", "pallas_pipelined")
+    assert EMITTER_NAMES == ("jax", "pallas")
     targets = {}
     for name in EMITTER_NAMES:
         em = get_emitter(name)
@@ -34,8 +30,7 @@ def test_registry_names_and_targets():
         assert em.info.name == name
         assert em.info.version >= 1
         targets[name] = em.info.target
-    assert targets == {"jax": "jax", "pallas": "pallas",
-                       "pallas_pipelined": "pallas"}
+    assert targets == {"jax": "jax", "pallas": "pallas"}
 
 
 def test_unknown_emitter_rejected():
@@ -49,13 +44,10 @@ def test_unknown_emitter_rejected():
 
 def test_default_emitters_contribute_no_cache_key():
     """Pre-registry configs must keep byte-identical fingerprints: the
-    default emitters map to None, only new backends are versioned."""
+    default emitters map to None."""
     assert emitter_cache_id(None) is None
     assert emitter_cache_id("jax") is None
     assert emitter_cache_id("pallas") is None
-    em = get_emitter("pallas_pipelined")
-    assert emitter_cache_id("pallas_pipelined") == \
-        f"pallas_pipelined@v{em.info.version}"
 
 
 def test_registry_emit_matches_direct_generator():
@@ -98,23 +90,6 @@ def test_deprecated_aliases_warn_and_match():
     assert pold.source == pnew.source
 
 
-# -- pipelined fallback golden contract -------------------------------------
-@pytest.mark.parametrize("name", TILE_NAMES)
-def test_pipelined_fallback_bit_identical(name):
-    """For every tile kernel, the pipelined emitter's interpret-mode
-    fallback source is byte-identical to what the synchronous emitter
-    produces under the same cost schedule — CPU runs lose nothing but
-    the async staging — and its async source verifies clean."""
-    piped = get_tile_op(name, schedule="cost", emitter="pallas_pipelined")
-    sync = get_tile_op(name, schedule="cost")
-    assert piped.pk.emitter == "pallas_pipelined"
-    assert piped.pk.fallback_source is not None
-    assert piped.pk.fallback_source == sync.pk.source
-    assert piped.pk.async_plan, f"{name}: nothing was pipelined"
-    rep = verify_pallas_kernel(piped.pk, piped.sk.ssa)
-    assert not rep.errors(), [f"[{f.code}] {f.message}" for f in rep.errors()]
-
-
 def _tile_inputs(prog, seed=0):
     # mirrors benchmarks.measure.tile_inputs_for, which cannot be
     # imported here: benchmarks entry points re-exec on import to pin
@@ -132,68 +107,39 @@ def _tile_inputs(prog, seed=0):
     return arrays, {s: 0.5 for s in prog.scalars}
 
 
-def test_pipelined_outputs_bit_identical_on_cpu():
-    for name in ("rmsnorm", "adamw", "softmax"):
-        piped = get_tile_op(name, schedule="cost",
-                            emitter="pallas_pipelined")
-        sync = get_tile_op(name, schedule="cost")
-        arrays, scalars = _tile_inputs(piped.sk.ssa.prog)
-        args = [jax.numpy.asarray(a) for a in arrays]
-        a = piped.apply(*args, **scalars)
-        b = sync.apply(*args, **scalars)
-        outs_a = a if isinstance(a, tuple) else (a,)
-        outs_b = b if isinstance(b, tuple) else (b,)
-        for x, y in zip(outs_a, outs_b):
-            assert np.array_equal(np.asarray(x), np.asarray(y)), name
-
-
-# -- mutation sensitivity ---------------------------------------------------
-def test_verifier_catches_unmatched_async_start():
-    """Planting one async start with no wait must surface as exactly
-    one error finding (the verifier neither misses it nor cascades)."""
-    op = get_tile_op("rmsnorm", schedule="cost",
-                     emitter="pallas_pipelined")
-    plan = op.pk.async_plan
-    assert len(plan) >= 2
-    clean = verify_async_plan(op.sk.ssa, op.pk.schedule, plan)
-    assert not [f for f in clean if f.severity == "error"]
-    mutated = plan[:-1] + (dataclasses.replace(plan[-1], wait_slot=-1),)
-    findings = verify_async_plan(op.sk.ssa, op.pk.schedule, mutated)
-    errors = [f for f in findings if f.severity == "error"]
-    assert len(errors) == 1
-    assert errors[0].code == "unmatched-async-start"
-    assert plan[-1].array in errors[0].message
-
-
-def test_verifier_catches_bad_parity_and_wait_order():
-    op = get_tile_op("rmsnorm", schedule="cost",
-                     emitter="pallas_pipelined")
-    plan = op.pk.async_plan
-    flipped = (dataclasses.replace(plan[0], sem=1 - plan[0].sem),) + plan[1:]
-    codes = {f.code for f in verify_async_plan(op.sk.ssa, op.pk.schedule,
-                                               flipped)
-             if f.severity == "error"}
-    assert "async-buffer-parity" in codes
-    early = (dataclasses.replace(plan[0],
-                                 wait_slot=plan[0].start_slot),) + plan[1:]
-    codes = {f.code for f in verify_async_plan(op.sk.ssa, op.pk.schedule,
-                                               early)
-             if f.severity == "error"}
-    assert "async-wait-order" in codes
+# -- the two emitters of one extraction agree -------------------------------
+@pytest.mark.parametrize("name", TILE_NAMES)
+def test_emitters_agree_on_every_tile_program(name):
+    """One ``get_tile_op`` build, run through its Pallas kernel (interpret
+    mode) and through its jnp twin, gives the same outputs. ``atol``
+    covers outputs that cancel to near zero (layernorm, rotary), where
+    one rounding step is a large relative error; it is below one f32
+    ulp at the O(1) magnitude of these outputs."""
+    op = get_tile_op(name)
+    assert op.pk is not None and op.pk.emitter == "pallas"
+    arrays, scalars = _tile_inputs(op.sk.ssa.prog)
+    args = [jax.numpy.asarray(a) for a in arrays]
+    got = op.apply(*args, interpret=True, **scalars)
+    want = op.jax_ref(*args, **scalars)
+    got = got if isinstance(got, tuple) else (got,)
+    want = want if isinstance(want, tuple) else (want,)
+    assert len(got) == len(want) == len(op.pk.out_arrays)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-6, atol=1e-7, err_msg=name)
 
 
 # -- acceptance sweep: both rule sets ---------------------------------------
 @pytest.mark.slow
 @pytest.mark.parametrize("tpu_rules", [False, True])
-def test_pipelined_verifies_clean_both_rule_sets(tpu_rules):
-    """Acceptance: pipelined emitter sources pass cheap verification
-    with zero errors across all 13 tile kernels under both the Table-I
-    rule set and the +TPU strength-reduction set."""
+def test_sync_sources_verify_clean_both_rule_sets(tpu_rules):
+    """Acceptance: Pallas emitter sources pass cheap verification with
+    zero errors across all 13 tile kernels under both the Table-I rule
+    set and the +TPU strength-reduction set."""
     for name in TILE_NAMES:
         cfg = SaturatorConfig(
             mode="accsat", cost_model="tpu_v5e", tpu_rules=tpu_rules,
-            schedule_cfg=ScheduleConfig(schedule="cost",
-                                        emitter="pallas_pipelined"))
+            schedule_cfg=ScheduleConfig(schedule="cost"))
         op = make_tile_op(PROGRAMS[name](), cfg)
         rep = verify_pallas_kernel(op.pk, op.sk.ssa)
         assert not rep.errors(), (name, tpu_rules,
